@@ -10,9 +10,9 @@ the solve.
 
 Rows are emitted under stable keys (``api/<name>``) into
 ``bench_results.json``; the ``overhead_us`` derived column is the
-per-solve facade cost and gates CI via ``benchmarks/run.py --strict``
-(a raised exception, not a threshold: dispatch regressions show up in the
-snapshot diff, hard failures in the gate).
+per-solve facade cost and gates CI via ``benchmarks/run.py``, which exits
+nonzero on a raised bench (an exception, not a threshold: dispatch
+regressions show up in the snapshot diff, hard failures in the gate).
 """
 from __future__ import annotations
 

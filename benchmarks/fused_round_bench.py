@@ -186,7 +186,7 @@ def run(m=2048, n=2048, rank=64, clients=4, observed=0.7):
     r16 = _quality(ps, cfg_q, jnp.bfloat16)
     bf16_ok = r16 < max(5.0 * r32, 2e-2)
     if not bf16_ok:
-        # Surfaces through ``run.py --strict`` as a failed bench: the
+        # Surfaces through ``run.py`` as a failed bench: the
         # compact data plane must never cost more than 5x recovery error.
         raise AssertionError(
             f"bf16 recovery error {r16:.3g} exceeds 5x f32 ({r32:.3g})"

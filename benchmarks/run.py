@@ -2,7 +2,8 @@
 
     PYTHONPATH=src python -m benchmarks.run [--full] [--only fig1,fig4]
 
-Prints ``name,us_per_call,derived`` CSV lines per bench.
+Prints ``name,us_per_call,derived`` CSV lines per bench.  Exits nonzero
+when any bench raised (after running the rest).
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ from benchmarks import (  # noqa: E402
     solver_runtime_bench,
     table1_upper_rank,
 )
+from repro.jax_cache import enable_persistent_cache  # noqa: E402
 
 BENCHES = {
     "fig1": fig1_convergence,
@@ -59,11 +61,10 @@ def main() -> None:
                     help="paper-scale problem sizes (slow on CPU)")
     ap.add_argument("--only", default=None,
                     help="comma-separated bench subset")
-    ap.add_argument("--strict", action="store_true",
-                    help="exit nonzero if any bench raised (CI gate)")
     ap.add_argument("--json-out", default=os.path.join(HERE,
                                                        "bench_results.json"))
     args = ap.parse_args()
+    enable_persistent_cache()
 
     names = list(BENCHES) if not args.only else args.only.split(",")
     all_rows = {}
@@ -79,7 +80,7 @@ def main() -> None:
     with open(args.json_out, "w") as f:
         json.dump(all_rows, f, indent=1, default=str)
     print(f"# wrote {args.json_out}")
-    if args.strict and failed:
+    if failed:
         sys.exit(f"benches raised: {', '.join(failed)}")
 
 

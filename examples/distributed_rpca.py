@@ -16,6 +16,7 @@ import jax
 
 from repro.core import DCFConfig, dcf_pca_sharded, generate_problem, relative_error
 from repro.launch.mesh import make_compat_mesh
+from repro.jax_cache import enable_persistent_cache
 
 
 def main():
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_persistent_cache()
     main()

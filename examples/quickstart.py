@@ -15,6 +15,7 @@ from repro.core import (
     DCFConfig, RunConfig, generate_problem, low_rank_relative_error,
     relative_error,
 )
+from repro.jax_cache import enable_persistent_cache
 
 
 def main():
@@ -69,4 +70,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_persistent_cache()
     main()

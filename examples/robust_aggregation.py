@@ -29,8 +29,8 @@ from repro.models import get_model
 from repro.models import params as pm
 from repro.training import optimizer as opt
 from repro.training.data import SyntheticData
-from repro.compat import shard_map_compat
 from repro.launch.mesh import make_compat_mesh
+from repro.jax_cache import enable_persistent_cache
 
 CORRUPT_WORKER = 3
 CORRUPT_DENSITY = 0.05
@@ -83,11 +83,12 @@ def make_step(model, mesh, mode, ocfg, rules):
         bspecs = jax.tree.map(
             lambda x: P(("data",), *(None,) * (x.ndim - 1)), batch)
         especs = jax.tree.map(lambda _: P("data"), err)
-        grads, err, loss = shard_map_compat(
-            per_worker, mesh,
-            (pspecs, especs, bspecs, P()),
-            (pspecs, especs, P()),
-            manual_axes=("data",),
+        grads, err, loss = jax.shard_map(
+            per_worker, mesh=mesh,
+            in_specs=(pspecs, especs, bspecs, P()),
+            out_specs=(pspecs, especs, P()),
+            axis_names=frozenset({"data"}),
+            check_vma=False,
         )(params, err, batch, key)
         params, state, _ = opt.update(ocfg, grads, state, params)
         return params, err, state, loss
@@ -132,4 +133,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_persistent_cache()
     main()

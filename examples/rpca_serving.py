@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.core import DCFConfig, QueueFull, generate_problem, relative_error
+from repro.jax_cache import enable_persistent_cache
 from repro.serving.gateway import GatewayConfig, RPCAGateway
 from repro.serving.rpca_service import RPCAService, RPCAServiceConfig
 
@@ -129,4 +130,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_persistent_cache()
     main()

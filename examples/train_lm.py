@@ -22,6 +22,7 @@ from repro.training import checkpoint as ckpt
 from repro.training import optimizer as opt
 from repro.training.data import SyntheticData
 from repro.training.train_step import make_train_step
+from repro.jax_cache import enable_persistent_cache
 
 
 def lm_100m():
@@ -70,4 +71,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_persistent_cache()
     main()

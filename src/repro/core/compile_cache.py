@@ -190,6 +190,11 @@ class CompileCache:
     def __contains__(self, key: Any) -> bool:
         return key in self._entries
 
+    def items(self) -> list[tuple[Any, Any]]:
+        """``(key, compiled executable)`` pairs, least recently used
+        first."""
+        return [(k, e.compiled) for k, e in self._entries.items()]
+
     @property
     def nbytes(self) -> int:
         """Total estimated footprint of the cached executables."""

@@ -40,7 +40,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import rpca as _rpca
-from repro.compat import shard_map_compat
 from repro.core import factorized as fz
 from repro.core import problems as prob
 from repro.core import runtime as rt
@@ -1465,7 +1464,8 @@ def _build_sharded(
                           packed.get("sched"), packed.get("faults"),
                           seg_extra)
 
-    fn = shard_map_compat(solve, mesh, (specs,), specs_out)
+    fn = jax.shard_map(solve, mesh=mesh, in_specs=(specs,),
+                       out_specs=specs_out, check_vma=False)
     return fn, args, n, ragged
 
 
@@ -1606,9 +1606,12 @@ def sharded_solve_hlo(
     m_obs: Array,
     cfg: fz.DCFConfig,
     mesh: Mesh,
+    *,
+    optimized: bool = True,
     **kwargs,
 ) -> str:
-    """Optimized HLO text of the jitted sharded solve, without running it.
+    """Optimized HLO text of the jitted sharded solve, without running it
+    (``optimized=False``: the lowered program, without compiling).
 
     This is the *measured* side of the consensus wire model: the bench
     (``benchmarks/consensus_bench.py``) feeds it to
@@ -1617,7 +1620,8 @@ def sharded_solve_hlo(
     top-k all-gather -- rather than trusting the analytic byte model.
     """
     fn, args, _, _ = _build_sharded(m_obs, cfg, mesh, **kwargs)
-    return jax.jit(fn).lower(args).compile().as_text()
+    lowered = jax.jit(fn).lower(args)
+    return lowered.compile().as_text() if optimized else lowered.as_text()
 
 
 def dcf_pca_sharded(

@@ -565,19 +565,20 @@ def _altmin_ctx(u: Array, rho: float, reduce_m=_identity):
     (ridge back-substitution against the once-factored ``G + rho I``).
     Shared by :func:`inner_solve_altmin` and the fused dual round so the
     Gram gemm / psum / Cholesky run once per local iteration."""
-    g = reduce_m(u.T @ u)  # (r, r)
+    g = reduce_m(core_ops.matmul(u.T, u))  # (r, r)
     g_reg = g + rho * jnp.eye(g.shape[0], dtype=g.dtype)
     cho = jax.scipy.linalg.cho_factor(g_reg)
 
     def update(v: Array, contr: Array) -> Array:
-        return jax.scipy.linalg.cho_solve(cho, g @ v.T + contr.T).T
+        return jax.scipy.linalg.cho_solve(
+            cho, core_ops.matmul(g, v.T) + contr.T).T
 
     return g, update
 
 
 def _gd_ctx(u: Array, rho: float, reduce_m=_identity):
     """Per-U Huber-GD context: one Lemma-1 step from the contraction."""
-    g = reduce_m(u.T @ u)
+    g = reduce_m(core_ops.matmul(u.T, u))
     step = 1.0 / (rho + core_ops.spectral_norm_ub_gram(g))
 
     def update(v: Array, contr: Array) -> Array:
@@ -618,7 +619,7 @@ def _u_step(cfg: DCFConfig, u_i: Array, v_i: Array, psi_v: Array,
     else:
         # For fixed (V, S) the U-subproblem is quadratic with Hessian
         # H = V^T V + rho (n_i/n) I  (r x r, local -- no collective).
-        gram_v = v_i.T @ v_i
+        gram_v = core_ops.matmul(v_i.T, v_i)
         if cfg.precondition == "newton":
             h = gram_v + n_frac * cfg.rho * jnp.eye(
                 gram_v.shape[0], dtype=gram_v.dtype
@@ -725,7 +726,7 @@ def finalize(u: Array, v: Array, m_blk: Array, lam: Array | float,
     ``L = U V^T`` is dense (the completion estimate extends to hidden
     entries); ``S`` is supported on the observed entries only.
     """
-    l_blk = u @ v.T
+    l_blk = core_ops.matmul(u, v.T)
     s_blk = kops.residual_shrink(u, v, m_blk, lam, w=w, impl=impl)
     return l_blk, s_blk
 
@@ -740,7 +741,7 @@ def local_objective(u: Array, v: Array, m_blk: Array, rho: float,
     """
     if w is not None and bitmask.is_packed(w):
         w = bitmask.unpack_mask(w, m_blk.shape[-1])
-    resid = m_blk.astype(jnp.float32) - u @ v.T
+    resid = m_blk.astype(jnp.float32) - core_ops.matmul(u, v.T)
     data = (
         core_ops.huber_loss(resid, lam)
         if w is None

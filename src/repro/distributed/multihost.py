@@ -79,10 +79,7 @@ def bootstrap(coordinator: str, num_processes: int, process_id: int,
         _force_host_devices(local_devices)
     import jax
 
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # pragma: no cover - GPU-only jaxlib
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = dict(
         coordinator_address=coordinator,
         num_processes=num_processes,
@@ -90,14 +87,10 @@ def bootstrap(coordinator: str, num_processes: int, process_id: int,
     )
     for attempt in range(max(1, connect_attempts)):
         try:
-            try:
-                # int(): the underlying pybind client rejects a float
-                # timeout *after* the coordinator service exists.
-                jax.distributed.initialize(
-                    **kwargs,
-                    initialization_timeout=int(connect_timeout_s))
-            except TypeError:  # pragma: no cover - older jaxlib signature
-                jax.distributed.initialize(**kwargs)
+            # int(): the underlying pybind client rejects a float timeout
+            # *after* the coordinator service exists.
+            jax.distributed.initialize(
+                **kwargs, initialization_timeout=int(connect_timeout_s))
             return
         except RuntimeError as e:
             if "only be called once" in str(e):

@@ -1,15 +1,7 @@
-"""Pallas-TPU version-compatibility aliases + shared kernel knobs.
-
-jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``
-(jax >= 0.5-era); resolve whichever this jax ships so the kernels run
-under both (interpret mode on CPU included).
-"""
+"""Interpret-mode resolution shared by every Pallas kernel entry point."""
 import os
 
 import jax
-from jax.experimental.pallas import tpu as _pltpu
-
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
 
 
 def should_interpret(interpret: "bool | None") -> bool:

@@ -140,7 +140,7 @@ def flash_attention(
             pltpu.VMEM((bq_, 1), jnp.float32),
             pltpu.VMEM((bq_, 1), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb)

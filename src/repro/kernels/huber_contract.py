@@ -35,6 +35,8 @@ plane, 8 columns per byte (``kernels.bitmask``) -- streamed as
 ``(bm, bn//8)`` tiles and unpacked to the (bm, bn) float tile with VPU
 shifts while the MXU runs the contraction.  Together they cut the
 steady-state HBM bytes of a masked pass ~2.2x (8 bytes/entry -> 2.125).
+The packed kernels run in interpret mode only: compiled for the TPU they
+are refused (see :data:`PACKED_MASK_UNSUPPORTED`).
 
 Dual contraction + epilogue diagnostics (the fused round primitive):
 :func:`huber_dual_contract` emits ``Psi^T U``, ``Psi V``, the Huber
@@ -66,6 +68,25 @@ DEFAULT_BM = 256
 DEFAULT_BN = 256
 LANE = 128
 
+#: The shrinkage threshold rides in SMEM as a (1, 1) f32 operand: kernel
+#: bodies read it as a scalar (``lam_ref[0, 0]``), and the TPU compiler only
+#: allows scalar loads from VMEM/SMEM refs.  Two dims, so a vmapped call
+#: with a per-problem ``lam`` (the serving lanes) still meets the block
+#: tiling rule: the batched block is (squeezed, 1, 1) of (B, 1, 1).
+_LAM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _lam_operand(lam: float | Array) -> Array:
+    return jnp.reshape(jnp.asarray(lam, jnp.float32), (1, 1))
+
+
+def _mxu(a: Array, b: Array) -> Array:
+    """f32 MXU product at full precision: Mosaic's default contracts f32
+    operands as one bf16 pass, ~3e-2 relative error on these contractions
+    (measured on a v5e), which caps solver recovery near 3e-3."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
 
 def _pad_to(x: Array, axis: int, mult: int) -> Array:
     pad = (-x.shape[axis]) % mult
@@ -87,11 +108,10 @@ def _contract_v_kernel(u_ref, v_ref, m_ref, lam_ref, out_ref):
     u = u_ref[...]  # (bm, r)
     v = v_ref[...]  # (bn, r)
     mt = m_ref[...]  # (bm, bn)
-    lam = lam_ref[0]
-    low = jnp.dot(u, v.T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u, v.T)
     psi = jnp.clip(mt.astype(jnp.float32) - low, -lam, lam)
-    out_ref[...] += jnp.dot(psi.T, u.astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+    out_ref[...] += _mxu(psi.T, u.astype(jnp.float32))
 
 
 def _contract_v_masked_kernel(u_ref, v_ref, m_ref, w_ref, lam_ref, out_ref):
@@ -103,13 +123,12 @@ def _contract_v_masked_kernel(u_ref, v_ref, m_ref, w_ref, lam_ref, out_ref):
     v = v_ref[...]  # (bn, r)
     mt = m_ref[...]  # (bm, bn)
     w = w_ref[...]  # (bm, bn) observation mask tile
-    lam = lam_ref[0]
-    low = jnp.dot(u, v.T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u, v.T)
     psi = w.astype(jnp.float32) * jnp.clip(
         mt.astype(jnp.float32) - low, -lam, lam
     )
-    out_ref[...] += jnp.dot(psi.T, u.astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+    out_ref[...] += _mxu(psi.T, u.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -123,11 +142,10 @@ def _contract_u_kernel(u_ref, v_ref, m_ref, lam_ref, out_ref):
     u = u_ref[...]  # (bm, r)
     v = v_ref[...]  # (bn, r)
     mt = m_ref[...]  # (bm, bn)
-    lam = lam_ref[0]
-    low = jnp.dot(u, v.T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u, v.T)
     psi = jnp.clip(mt.astype(jnp.float32) - low, -lam, lam)
-    out_ref[...] += jnp.dot(psi, v.astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+    out_ref[...] += _mxu(psi, v.astype(jnp.float32))
 
 
 def _contract_u_masked_kernel(u_ref, v_ref, m_ref, w_ref, lam_ref, out_ref):
@@ -139,13 +157,12 @@ def _contract_u_masked_kernel(u_ref, v_ref, m_ref, w_ref, lam_ref, out_ref):
     v = v_ref[...]  # (bn, r)
     mt = m_ref[...]  # (bm, bn)
     w = w_ref[...]  # (bm, bn) observation mask tile
-    lam = lam_ref[0]
-    low = jnp.dot(u, v.T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u, v.T)
     psi = w.astype(jnp.float32) * jnp.clip(
         mt.astype(jnp.float32) - low, -lam, lam
     )
-    out_ref[...] += jnp.dot(psi, v.astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+    out_ref[...] += _mxu(psi, v.astype(jnp.float32))
 
 
 # Canonical resolution lives in kernels.compat (env-aware, one pattern
@@ -174,7 +191,7 @@ def huber_contract_v(
     v_p = _pad_to(_pad_to(v, 0, bn), 1, LANE)
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[1] // bn, m_p.shape[0] // bm)  # (n-blocks, m-blocks)
     out = pl.pallas_call(
@@ -184,11 +201,11 @@ def huber_contract_v(
             pl.BlockSpec((bm, r_pad), lambda i, j: (j, 0)),
             pl.BlockSpec((bn, r_pad), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, bn), lambda i, j: (j, i)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _LAM_SPEC,
         ],
         out_specs=pl.BlockSpec((bn, r_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((v_p.shape[0], r_pad), jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, lam_arr)
     return out[:n, :r]
@@ -213,7 +230,7 @@ def huber_contract_u(
     v_p = _pad_to(_pad_to(v, 0, bn), 1, LANE)
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[0] // bm, m_p.shape[1] // bn)  # (m-blocks, n-blocks)
     out = pl.pallas_call(
@@ -223,11 +240,11 @@ def huber_contract_u(
             pl.BlockSpec((bm, r_pad), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, r_pad), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _LAM_SPEC,
         ],
         out_specs=pl.BlockSpec((bm, r_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((u_p.shape[0], r_pad), jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, lam_arr)
     return out[:mm, :r]
@@ -259,7 +276,7 @@ def huber_contract_v_masked(
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     w_p = _pad_to(_pad_to(w, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[1] // bn, m_p.shape[0] // bm)  # (n-blocks, m-blocks)
     out = pl.pallas_call(
@@ -270,11 +287,11 @@ def huber_contract_v_masked(
             pl.BlockSpec((bn, r_pad), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, bn), lambda i, j: (j, i)),
             pl.BlockSpec((bm, bn), lambda i, j: (j, i)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _LAM_SPEC,
         ],
         out_specs=pl.BlockSpec((bn, r_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((v_p.shape[0], r_pad), jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, w_p, lam_arr)
     return out[:n, :r]
@@ -301,7 +318,7 @@ def huber_contract_u_masked(
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     w_p = _pad_to(_pad_to(w, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[0] // bm, m_p.shape[1] // bn)  # (m-blocks, n-blocks)
     out = pl.pallas_call(
@@ -312,11 +329,11 @@ def huber_contract_u_masked(
             pl.BlockSpec((bn, r_pad), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _LAM_SPEC,
         ],
         out_specs=pl.BlockSpec((bm, r_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((u_p.shape[0], r_pad), jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, w_p, lam_arr)
     return out[:mm, :r]
@@ -329,6 +346,20 @@ def huber_contract_u_masked(
 # block-wise over consecutive j steps; out_v stays grid-resident in VMEM
 # (constant block index) and is flushed once; the scalars live in SMEM.
 # ---------------------------------------------------------------------------
+#: Why the packed-mask kernels run in interpret mode only.  Compiled for
+#: the TPU, the (bm, bn//8) uint8 tile breaks the (8, 128) block tiling,
+#: Mosaic has no uint8 -> f32 cast, and the interleaved unpack (bit k of
+#: byte j is column 8j + k) needs a lane shuffle it lowers only through a
+#: 16x-padded VMEM temporary.  A bit-planar layout would lower; until the
+#: layout changes, a compiled packed kernel is refused rather than unpacked
+#: behind the caller's back.
+PACKED_MASK_UNSUPPORTED = (
+    "bit-packed masks are not supported by the compiled TPU kernels yet "
+    "(the packed layout does not lower); pass a dense 0/1 mask "
+    "(DCFConfig.pack_mask=False)"
+)
+
+
 def _unpack_w_tile(wp: Array, bn: int) -> Array:
     """(bm, bn//8) uint8 tile -> (bm, bn) f32 0/1 tile (VPU shifts).
 
@@ -374,8 +405,8 @@ def _make_dual_kernel(mask_mode: str, bn: int, with_v: bool, with_u: bool,
 
         u = u_ref[...]  # (bm, r)
         v = v_ref[...]  # (bn, r)
-        lam = lam_ref[0]
-        low = jnp.dot(u, v.T, preferred_element_type=jnp.float32)
+        lam = lam_ref[0, 0]
+        low = _mxu(u, v.T)
         r = m_ref[...].astype(jnp.float32) - low
         rw = r if w is None else w * r
         psi = jnp.clip(rw, -lam, lam)
@@ -388,14 +419,10 @@ def _make_dual_kernel(mask_mode: str, bn: int, with_v: bool, with_u: bool,
             )
             psi2_ref[0, 0] += jnp.sum(psi * psi)
         if with_u:
-            out_u_ref[...] += jnp.dot(psi, v.astype(jnp.float32),
-                                      preferred_element_type=jnp.float32)
+            out_u_ref[...] += _mxu(psi, v.astype(jnp.float32))
         if with_v:
             blk = pl.multiple_of(j * bn, bn)
-            out_v_ref[pl.ds(blk, bn), :] += jnp.dot(
-                psi.T, u.astype(jnp.float32),
-                preferred_element_type=jnp.float32,
-            )
+            out_v_ref[pl.ds(blk, bn), :] += _mxu(psi.T, u.astype(jnp.float32))
 
     return kernel
 
@@ -408,7 +435,7 @@ def _dual_call(u, v, m, w, lam, bm, bn, interpret, with_v, with_u=True,
     v_p = _pad_to(_pad_to(v, 0, bn), 1, LANE)
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
     n_p = m_p.shape[1]
 
     if w is None:
@@ -416,6 +443,8 @@ def _dual_call(u, v, m, w, lam, bm, bn, interpret, with_v, with_u=True,
         operands = (u_p, v_p, m_p, lam_arr)
         w_specs = []
     elif bitmask.is_packed(w):
+        if not _should_interpret(interpret):
+            raise NotImplementedError(PACKED_MASK_UNSUPPORTED)
         if bn % bitmask.PACK:
             raise ValueError(f"bn={bn} must be a multiple of {bitmask.PACK} "
                              "for bit-packed masks")
@@ -457,11 +486,11 @@ def _dual_call(u, v, m, w, lam, bm, bn, interpret, with_v, with_u=True,
             pl.BlockSpec((bn, r_pad), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             *w_specs,
-            pl.BlockSpec(memory_space=pl.ANY),
+            _LAM_SPEC,
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=_should_interpret(interpret),

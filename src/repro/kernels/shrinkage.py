@@ -25,12 +25,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 from repro.kernels.huber_contract import (
+    _LAM_SPEC,
     DEFAULT_BM,
     DEFAULT_BN,
     LANE,
+    _lam_operand,
+    _mxu,
     _pad_to,
     _should_interpret,
 )
@@ -39,15 +40,15 @@ Array = jax.Array
 
 
 def _shrink_kernel(u_ref, v_ref, m_ref, lam_ref, s_ref):
-    lam = lam_ref[0]
-    low = jnp.dot(u_ref[...], v_ref[...].T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u_ref[...], v_ref[...].T)
     r = m_ref[...].astype(jnp.float32) - low
     s_ref[...] = jnp.sign(r) * jnp.maximum(jnp.abs(r) - lam, 0.0)
 
 
 def _shrink_psi_kernel(u_ref, v_ref, m_ref, lam_ref, s_ref, psi_ref):
-    lam = lam_ref[0]
-    low = jnp.dot(u_ref[...], v_ref[...].T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u_ref[...], v_ref[...].T)
     r = m_ref[...].astype(jnp.float32) - low
     s = jnp.sign(r) * jnp.maximum(jnp.abs(r) - lam, 0.0)
     s_ref[...] = s
@@ -55,8 +56,8 @@ def _shrink_psi_kernel(u_ref, v_ref, m_ref, lam_ref, s_ref, psi_ref):
 
 
 def _shrink_masked_kernel(u_ref, v_ref, m_ref, w_ref, lam_ref, s_ref):
-    lam = lam_ref[0]
-    low = jnp.dot(u_ref[...], v_ref[...].T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u_ref[...], v_ref[...].T)
     r = m_ref[...].astype(jnp.float32) - low
     s_ref[...] = w_ref[...].astype(jnp.float32) * (
         jnp.sign(r) * jnp.maximum(jnp.abs(r) - lam, 0.0)
@@ -65,8 +66,8 @@ def _shrink_masked_kernel(u_ref, v_ref, m_ref, w_ref, lam_ref, s_ref):
 
 def _shrink_psi_masked_kernel(u_ref, v_ref, m_ref, w_ref, lam_ref, s_ref,
                               psi_ref):
-    lam = lam_ref[0]
-    low = jnp.dot(u_ref[...], v_ref[...].T, preferred_element_type=jnp.float32)
+    lam = lam_ref[0, 0]
+    low = _mxu(u_ref[...], v_ref[...].T)
     w = w_ref[...].astype(jnp.float32)
     r = m_ref[...].astype(jnp.float32) - low
     s = w * (jnp.sign(r) * jnp.maximum(jnp.abs(r) - lam, 0.0))
@@ -79,7 +80,7 @@ def _specs(bm: int, bn: int, r_pad: int):
         pl.BlockSpec((bm, r_pad), lambda i, j: (i, 0)),
         pl.BlockSpec((bn, r_pad), lambda i, j: (j, 0)),
         pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        pl.BlockSpec(memory_space=pl.ANY),
+        _LAM_SPEC,
     ]
 
 
@@ -89,7 +90,7 @@ def _specs_masked(bm: int, bn: int, r_pad: int):
         pl.BlockSpec((bn, r_pad), lambda i, j: (j, 0)),
         pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        pl.BlockSpec(memory_space=pl.ANY),
+        _LAM_SPEC,
     ]
 
 
@@ -110,7 +111,7 @@ def residual_shrink(
     v_p = _pad_to(_pad_to(v, 0, bn), 1, LANE)
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[0] // bm, m_p.shape[1] // bn)
     s = pl.pallas_call(
@@ -119,7 +120,7 @@ def residual_shrink(
         in_specs=_specs(bm, bn, r_pad),
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(m_p.shape, jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, lam_arr)
     return s[:mm, :n]
@@ -142,7 +143,7 @@ def residual_shrink_psi(
     v_p = _pad_to(_pad_to(v, 0, bn), 1, LANE)
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[0] // bm, m_p.shape[1] // bn)
     s, psi = pl.pallas_call(
@@ -157,7 +158,7 @@ def residual_shrink_psi(
             jax.ShapeDtypeStruct(m_p.shape, jnp.float32),
             jax.ShapeDtypeStruct(m_p.shape, jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, lam_arr)
     return s[:mm, :n], psi[:mm, :n]
@@ -183,7 +184,7 @@ def residual_shrink_masked(
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     w_p = _pad_to(_pad_to(w, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[0] // bm, m_p.shape[1] // bn)
     s = pl.pallas_call(
@@ -192,7 +193,7 @@ def residual_shrink_masked(
         in_specs=_specs_masked(bm, bn, r_pad),
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(m_p.shape, jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, w_p, lam_arr)
     return s[:mm, :n]
@@ -218,7 +219,7 @@ def residual_shrink_psi_masked(
     m_p = _pad_to(_pad_to(m, 0, bm), 1, bn)
     w_p = _pad_to(_pad_to(w, 0, bm), 1, bn)
     r_pad = u_p.shape[1]
-    lam_arr = jnp.asarray([lam], jnp.float32)
+    lam_arr = _lam_operand(lam)
 
     grid = (m_p.shape[0] // bm, m_p.shape[1] // bn)
     s, psi = pl.pallas_call(
@@ -233,7 +234,7 @@ def residual_shrink_psi_masked(
             jax.ShapeDtypeStruct(m_p.shape, jnp.float32),
             jax.ShapeDtypeStruct(m_p.shape, jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=_should_interpret(interpret),
     )(u_p, v_p, m_p, w_p, lam_arr)
     return s[:mm, :n], psi[:mm, :n]

@@ -33,6 +33,10 @@ from repro.roofline import analysis
 from repro.training import optimizer as opt
 from repro.training.train_step import make_train_step
 
+#: The chip whose pods the dry run models: its roofline terms use this
+#: kind's peaks (the compile itself runs on forced host devices).
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _sds(shapes, shardings):
     return jax.tree.map(
@@ -194,8 +198,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         mem = compiled.memory_analysis()
 
     roof = analysis.analyze(
-        compiled, arch=arch, shape=shape_name, mesh_name=mesh_name,
-        n_devices=mesh.size,
+        compiled, device_kind=TARGET_DEVICE_KIND, arch=arch,
+        shape=shape_name, mesh_name=mesh_name, n_devices=mesh.size,
         model_flops_global=model_flops_global(cfg, model, shape),
     )
     rec = roof.to_dict()

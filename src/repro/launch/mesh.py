@@ -7,19 +7,15 @@ module never touches jax device state -- the dry-run must set
 from __future__ import annotations
 
 import jax
-import numpy as np
 from jax.sharding import Mesh
 
 
 def make_compat_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with explicit-auto axes on jax >= 0.5; plain Mesh
-    construction (all axes auto by default) on older jax."""
-    try:
-        kinds = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=kinds)
-    except AttributeError:
-        n = int(np.prod(shape))
-        return Mesh(np.array(jax.devices()[:n]).reshape(shape), axes)
+    """``jax.make_mesh`` with every axis ``Auto``: ``jax.make_mesh``
+    defaults to ``Explicit`` axes, which the solvers' ``shard_map`` and
+    ``NamedSharding`` code does not use."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -1,6 +1,7 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), all in seconds (TPU v5e targets):
+Three terms per (arch x shape x mesh), all in seconds, against the peaks
+of the chip named by its ``device_kind`` (:data:`PEAKS`):
 
     compute    = HLO_FLOPs_per_device / peak_FLOPs_per_chip
     memory     = HLO_bytes_per_device / HBM_bandwidth
@@ -19,10 +20,38 @@ import dataclasses
 import json
 import re
 
-# TPU v5e hardware constants (per chip), per the assignment.
-PEAK_FLOPS_BF16 = 197e12
-HBM_BW = 819e9
-ICI_LINK_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Per-chip peaks: bf16 FLOP/s, HBM bytes/s, one ICI link's bytes/s."""
+
+    flops_bf16: float
+    hbm_bw: float
+    ici_link_bw: float
+    source: str
+
+
+#: Peaks keyed by ``jax.Device.device_kind``.  A kind missing here is an
+#: error (:func:`peaks_for`), never a silent default.
+PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "819 GB/s HBM, 1,600 Gbit/s ICI per chip (4 links)",
+    ),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; raises ``ValueError`` for a chip with
+    no entry in :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device_kind {device_kind!r}; known kinds: "
+            f"{', '.join(sorted(PEAKS))}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -75,6 +104,7 @@ def collective_bytes(hlo_text: str) -> dict[str, int]:
 
 @dataclasses.dataclass
 class Roofline:
+    device_kind: str
     arch: str
     shape: str
     mesh: str
@@ -86,17 +116,24 @@ class Roofline:
     model_flops_global: float
     peak_memory_per_device: float
 
+    def __post_init__(self) -> None:
+        peaks_for(self.device_kind)  # reject an unknown chip up front
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return peaks_for(self.device_kind)
+
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS_BF16
+        return self.flops_per_device / self.peaks.flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_device / ICI_LINK_BW
+        return self.coll_bytes_per_device / self.peaks.ici_link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -122,7 +159,7 @@ class Roofline:
         """Useful-compute roofline fraction (the score): time the chips
         *must* spend on model FLOPs divided by the bound step time."""
         ideal = self.model_flops_global / (
-            self.n_devices * PEAK_FLOPS_BF16)
+            self.n_devices * self.peaks.flops_bf16)
         return ideal / self.roofline_time if self.roofline_time else 0.0
 
     def to_dict(self) -> dict:
@@ -137,9 +174,11 @@ class Roofline:
         return d
 
 
-def analyze(compiled, *, arch: str, shape: str, mesh_name: str,
-            n_devices: int, model_flops_global: float) -> Roofline:
-    """Roofline terms from the compiled artifact.
+def analyze(compiled, *, device_kind: str, arch: str, shape: str,
+            mesh_name: str, n_devices: int,
+            model_flops_global: float) -> Roofline:
+    """Roofline terms from the compiled artifact, against the peaks of
+    ``device_kind`` (the chip the artifact was compiled for).
 
     Uses the loop-aware HLO walker (repro.roofline.hlo_costs) rather than
     ``compiled.cost_analysis()``: XLA's built-in analysis counts ``while``
@@ -159,7 +198,7 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str,
         - getattr(mem, "alias_size_in_bytes", 0)
     )
     return Roofline(
-        arch=arch, shape=shape, mesh=mesh_name, n_devices=n_devices,
+        device_kind=device_kind, arch=arch, shape=shape, mesh=mesh_name, n_devices=n_devices,
         flops_per_device=float(costs.flops),
         bytes_per_device=float(costs.bytes),
         coll_bytes_per_device=float(sum(coll.values())),

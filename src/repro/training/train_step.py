@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map_compat
 from repro.configs.base import ModelConfig
 from repro.distributed import grad_compress as gc
 from repro.distributed.sharding import ShardingRules
@@ -118,12 +117,13 @@ def make_robust_train_step(
         batch_specs = jax.tree.map(
             lambda x: P(dp_axes, *(None,) * (x.ndim - 1)), batch)
         param_specs = jax.tree.map(lambda _: P(), params)
-        grads, loss, mets = shard_map_compat(
+        grads, loss, mets = jax.shard_map(
             per_worker,
-            mesh,
-            (param_specs, batch_specs, P()),
-            (param_specs, P(), P()),
-            manual_axes=dp_axes,
+            mesh=mesh,
+            in_specs=(param_specs, batch_specs, P()),
+            out_specs=(param_specs, P(), P()),
+            axis_names=frozenset(dp_axes),
+            check_vma=False,
         )(params, batch, key)
         params, opt_state, om = opt.update(opt_cfg, grads, opt_state, params)
         return params, opt_state, {"loss": loss, **mets, **om}

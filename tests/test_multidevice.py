@@ -10,10 +10,9 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Version-compat mesh constructor prepended to every subprocess snippet
-# (the snippets run with PYTHONPATH=src, so the repo's shared helper is
-# importable).
-COMPAT = """
+# Mesh constructor prepended to every subprocess snippet (the snippets run
+# with PYTHONPATH=src, so the repo's shared helper is importable).
+PRELUDE = """
 from repro.launch.mesh import make_compat_mesh as compat_mesh
 """
 
@@ -23,7 +22,7 @@ def run_py(code: str, timeout=900) -> str:
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run(
-        [sys.executable, "-c", COMPAT + textwrap.dedent(code)],
+        [sys.executable, "-c", PRELUDE + textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,
     )
     assert out.returncode == 0, f"stderr:\n{out.stderr}\nstdout:\n{out.stdout}"
@@ -141,7 +140,6 @@ def test_robust_grad_aggregation_byzantine():
     out = run_py("""
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed.grad_compress import (CompressConfig,
                                                      consensus_compress)
         mesh = compat_mesh((8,), ("data",))
@@ -164,8 +162,9 @@ def test_robust_grad_aggregation_byzantine():
             out = consensus_compress(g, ("data",), ccfg,
                                      jax.random.PRNGKey(7))
             return out[None]
-        fn = shard_map(agg, mesh=mesh, in_specs=(P("data", None, None),),
-                       out_specs=P("data", None, None), check_rep=False)
+        fn = jax.shard_map(agg, mesh=mesh,
+                           in_specs=(P("data", None, None),),
+                           out_specs=P("data", None, None), check_vma=False)
         robust = jax.jit(fn)(grads)[0]
 
         err_robust = float(jnp.linalg.norm(robust - clean_mean)

@@ -311,7 +311,6 @@ def test_two_process_collectives_smoke():
     crosses the process boundary."""
     outs = mh.launch_workers(_WORKER_COMMON + """
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map_compat
 assert jax.process_count() == 2, jax.process_count()
 mesh = mh.multihost_mesh()
 assert mh.is_multiprocess_mesh(mesh)
@@ -320,7 +319,8 @@ x = np.arange(2, dtype=np.float32)
 def body(xl):
     return jax.lax.psum(xl, "data")
 
-fn = shard_map_compat(body, mesh, (P("data"),), P(None))
+fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+                   out_specs=P(None), check_vma=False)
 out = jax.jit(fn)(jax.device_put(
     x, jax.sharding.NamedSharding(mesh, P("data"))))
 total = float(np.asarray(out)[0])

@@ -29,10 +29,7 @@ def test_flops_scale_with_trip_count(n):
 
 
 def _builtin_flops(compiled):
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax wraps the dict in a 1-list
-        ca = ca[0]
-    return ca.get("flops")
+    return compiled.cost_analysis()["flops"]
 
 
 def test_builtin_cost_analysis_undercounts():
@@ -74,7 +71,7 @@ def test_bytes_unique_convention():
 
 def test_roofline_terms_and_bottleneck():
     r = analysis.Roofline(
-        arch="x", shape="y", mesh="16x16", n_devices=256,
+        device_kind="TPU v5 lite", arch="x", shape="y", mesh="16x16", n_devices=256,
         flops_per_device=197e12 * 0.010,  # 10 ms compute
         bytes_per_device=819e9 * 0.002,  # 2 ms memory
         coll_bytes_per_device=50e9 * 0.004,  # 4 ms collective
@@ -85,6 +82,20 @@ def test_roofline_terms_and_bottleneck():
     assert r.bottleneck == "compute"
     assert abs(r.useful_flops_ratio - 0.8) < 1e-9
     assert abs(r.roofline_fraction - 0.8) < 1e-9
+
+
+def test_unknown_device_kind_rejected():
+    """A chip with no peaks entry is an error, not a silent v5e default."""
+    assert analysis.peaks_for("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no peaks for device_kind"):
+        analysis.peaks_for("cpu")
+    with pytest.raises(ValueError, match="no peaks for device_kind"):
+        analysis.Roofline(
+            device_kind="TPU v9 imaginary", arch="x", shape="y", mesh="1",
+            n_devices=1, flops_per_device=1.0, bytes_per_device=1.0,
+            coll_bytes_per_device=0.0, coll_breakdown={},
+            model_flops_global=1.0, peak_memory_per_device=0.0,
+        )
 
 
 def test_dryrun_artifacts_complete():

@@ -38,7 +38,7 @@ _COLLECTIVES = {
 }
 _TAINT_SOURCE_CALLS = {"axis_index", "process_index"}
 _STATIC_ATTRS = {"ndim", "shape", "dtype", "size", "sharding"}
-_SHARD_MAP_NAMES = {"shard_map", "shard_map_compat", "pmap", "jax.pmap"}
+_SHARD_MAP_NAMES = {"shard_map", "pmap", "jax.pmap"}
 
 
 def _call_basename(node: ast.Call) -> str | None:
@@ -53,7 +53,7 @@ def _call_basename(node: ast.Call) -> str | None:
 
 def _shard_map_body_names(mod: ModuleInfo) -> dict[str, int]:
     """Names of functions passed (positionally or by reference) to
-    shard_map / shard_map_compat / pmap -> call line."""
+    shard_map / pmap -> call line."""
     out: dict[str, int] = {}
     for node in ast.walk(mod.tree):
         if not isinstance(node, ast.Call):
