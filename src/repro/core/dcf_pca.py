@@ -324,108 +324,110 @@ def _make_wire_solver(cfg: fz.DCFConfig, track: bool) -> rt.Solver:
         u_i, pt, v_mask = _inject_round_faults(p, tg, u_i, u_used)
         v = (v_new if v_mask is None
              else jnp.where(v_mask[:, None, None] > 0, v_new, c["v"]))
-        wsum = None
-        if robust:
-            # One vote per client: unweighted deltas cross the wire; the
-            # robust combine happens on the receive side.
-            w = jnp.ones((e,), jnp.float32)
-        elif pt is None:
-            if p.n_cols is None:
-                w = jnp.full((e,), 1.0 / e, jnp.float32)
+        with jax.named_scope(fz.CONSENSUS_SCOPE):
+            wsum = None
+            if robust:
+                # One vote per client: unweighted deltas cross the wire; the
+                # robust combine happens on the receive side.
+                w = jnp.ones((e,), jnp.float32)
+            elif pt is None:
+                if p.n_cols is None:
+                    w = jnp.full((e,), 1.0 / e, jnp.float32)
+                else:
+                    w, _ = fz.consensus_weights(p.n_cols, None, e)
             else:
-                w, _ = fz.consensus_weights(p.n_cols, None, e)
-        else:
-            w, wsum = fz.consensus_weights(p.n_cols, pt, e)
-            u_i = jnp.where(pt[:, None, None] > 0, u_i, u_used)
-        # What crosses the wire: each client's weighted delta (their sum
-        # is the consensus step; a dropped client's w is 0, an all-dropout
-        # round sums to an exact no-op).  Robust aggregators ship the
-        # *unweighted* delta and combine one-vote on receive.
-        contrib = (w[:, None, None] * (u_i - u_used)).astype(jnp.float32)
-        out = dict(c)
-        if compress is None:
-            if robust or screen is not None:
-                act = jnp.ones((e,), jnp.float32) if pt is None else pt
-                if screen is not None:
-                    act = act * gcomp.divergence_screen_mask(
-                        contrib, act, screen
-                    )
+                w, wsum = fz.consensus_weights(p.n_cols, pt, e)
+                u_i = jnp.where(pt[:, None, None] > 0, u_i, u_used)
+            # What crosses the wire: each client's weighted delta (their sum
+            # is the consensus step; a dropped client's w is 0, an all-dropout
+            # round sums to an exact no-op).  Robust aggregators ship the
+            # *unweighted* delta and combine one-vote on receive.
+            contrib = (w[:, None, None] * (u_i - u_used)).astype(jnp.float32)
+            out = dict(c)
+            if compress is None:
+                if robust or screen is not None:
+                    act = jnp.ones((e,), jnp.float32) if pt is None else pt
+                    if screen is not None:
+                        act = act * gcomp.divergence_screen_mask(
+                            contrib, act, screen
+                        )
+                    if robust:
+                        delta, cnt = gcomp.robust_combine_stacked(
+                            contrib, act, cfg.aggregator, cfg.trim_frac
+                        )
+                        wsum = cnt.astype(jnp.float32)
+                    else:
+                        # Screened weighted mean: recompute the weights over
+                        # the survivors (contrib already carries the original
+                        # w, so rescale by the survivor renormalization).
+                        w2, wsum = fz.consensus_weights(p.n_cols, act, e)
+                        deltas = (u_i - u_used).astype(jnp.float32)
+                        delta = jnp.sum(
+                            w2[:, None, None]
+                            * jnp.where(act[:, None, None] > 0, deltas, 0.0),
+                            axis=0,
+                        )
+                        delta = jnp.where(wsum > 0, delta, 0.0)
+                else:
+                    delta = contrib.sum(axis=0)
+            else:
+                k = mh.topk_k(u_used.size, compress.topk_frac)
+                flat = (contrib + c["err"]).reshape(e, -1)
+                vals, idx = jax.vmap(lambda x: gcomp.topk_sparsify(x, k))(flat)
+                recon = jax.vmap(
+                    lambda vv, ii: gcomp.topk_reconstruct(vv, ii,
+                                                          flat.shape[1])
+                )(vals, idx)
+                err_new = (flat - recon).reshape(c["err"].shape)
+                if pt is not None:
+                    # Dropped clients ship nothing and keep their residual.
+                    vals = jnp.where(pt[:, None] > 0, vals, 0.0)
+                    err_new = jnp.where(pt[:, None, None] > 0, err_new,
+                                        c["err"])
                 if robust:
+                    # A poisoned payload must not poison its own error-
+                    # feedback carry forever: non-finite residuals reset.
+                    err_new = jnp.where(jnp.isfinite(err_new), err_new, 0.0)
+                    act = jnp.ones((e,), jnp.float32) if pt is None else pt
+                    if screen is not None:
+                        # Judged on the *shipped* payload norms.
+                        nrm = jnp.sqrt(jnp.sum(vals * vals, axis=1))
+                        act = act * gcomp.screen_from_norms(nrm, act, screen)
                     delta, cnt = gcomp.robust_combine_stacked(
-                        contrib, act, cfg.aggregator, cfg.trim_frac
+                        recon.reshape((e,) + u_used.shape), act,
+                        cfg.aggregator, cfg.trim_frac,
                     )
                     wsum = cnt.astype(jnp.float32)
                 else:
-                    # Screened weighted mean: recompute the weights over
-                    # the survivors (contrib already carries the original
-                    # w, so rescale by the survivor renormalization).
-                    w2, wsum = fz.consensus_weights(p.n_cols, act, e)
-                    deltas = (u_i - u_used).astype(jnp.float32)
-                    delta = jnp.sum(
-                        w2[:, None, None]
-                        * jnp.where(act[:, None, None] > 0, deltas, 0.0),
-                        axis=0,
-                    )
-                    delta = jnp.where(wsum > 0, delta, 0.0)
+                    delta = gcomp.topk_reconstruct(
+                        vals, idx, flat.shape[1]).reshape(u_used.shape)
+                out["err"] = err_new
+            if delay == 0:
+                u = u_used + delta
             else:
-                delta = contrib.sum(axis=0)
-        else:
-            k = mh.topk_k(u_used.size, compress.topk_frac)
-            flat = (contrib + c["err"]).reshape(e, -1)
-            vals, idx = jax.vmap(lambda x: gcomp.topk_sparsify(x, k))(flat)
-            recon = jax.vmap(
-                lambda vv, ii: gcomp.topk_reconstruct(vv, ii, flat.shape[1])
-            )(vals, idx)
-            err_new = (flat - recon).reshape(c["err"].shape)
-            if pt is not None:
-                # Dropped clients ship nothing and keep their residual.
-                vals = jnp.where(pt[:, None] > 0, vals, 0.0)
-                err_new = jnp.where(pt[:, None, None] > 0, err_new,
-                                    c["err"])
-            if robust:
-                # A poisoned payload must not poison its own error-
-                # feedback carry forever: non-finite residuals reset.
-                err_new = jnp.where(jnp.isfinite(err_new), err_new, 0.0)
-                act = jnp.ones((e,), jnp.float32) if pt is None else pt
-                if screen is not None:
-                    # Judged on the *shipped* payload norms.
-                    nrm = jnp.sqrt(jnp.sum(vals * vals, axis=1))
-                    act = act * gcomp.screen_from_norms(nrm, act, screen)
-                delta, cnt = gcomp.robust_combine_stacked(
-                    recon.reshape((e,) + u_used.shape), act,
-                    cfg.aggregator, cfg.trim_frac,
+                # Guard scalar: the fused epilogue's ||Psi||_F^2 (free since
+                # the PR-5 kernels) or, with fused="off", the consensus-step
+                # energy.  Divergence under staleness shows up as growth in
+                # either; the trip is sticky -- once synchronous, stays
+                # synchronous.
+                if diag_i is not None:
+                    scalar = diag_i[1].sum()
+                else:
+                    scalar = jnp.sum(delta * delta)
+                # Trip on guard-factor growth OR a non-finite scalar (a hard
+                # blowup must not slip through: NaN compares False with
+                # everything, so the growth test alone would never fire).
+                trip = jnp.logical_or(
+                    ~jnp.isfinite(scalar),
+                    jnp.isfinite(c["guard"])
+                    & (scalar > cfg.stale_guard * c["guard"]),
                 )
-                wsum = cnt.astype(jnp.float32)
-            else:
-                delta = gcomp.topk_reconstruct(
-                    vals, idx, flat.shape[1]).reshape(u_used.shape)
-            out["err"] = err_new
-        if delay == 0:
-            u = u_used + delta
-        else:
-            # Guard scalar: the fused epilogue's ||Psi||_F^2 (free since
-            # the PR-5 kernels) or, with fused="off", the consensus-step
-            # energy.  Divergence under staleness shows up as growth in
-            # either; the trip is sticky -- once synchronous, stays
-            # synchronous.
-            if diag_i is not None:
-                scalar = diag_i[1].sum()
-            else:
-                scalar = jnp.sum(delta * delta)
-            # Trip on guard-factor growth OR a non-finite scalar (a hard
-            # blowup must not slip through: NaN compares False with
-            # everything, so the growth test alone would never fire).
-            trip = jnp.logical_or(
-                ~jnp.isfinite(scalar),
-                jnp.isfinite(c["guard"])
-                & (scalar > cfg.stale_guard * c["guard"]),
-            )
-            sync = jnp.logical_or(c["sync"], trip)
-            u = u_used + c["pending"] + jnp.where(sync, delta,
-                                                  jnp.zeros_like(delta))
-            out["pending"] = jnp.where(sync, jnp.zeros_like(delta), delta)
-            out["sync"] = sync
-            out["guard"] = scalar
+                sync = jnp.logical_or(c["sync"], trip)
+                u = u_used + c["pending"] + jnp.where(sync, delta,
+                                                      jnp.zeros_like(delta))
+                out["pending"] = jnp.where(sync, jnp.zeros_like(delta), delta)
+                out["sync"] = sync
+                out["guard"] = scalar
         if fused_obj:
             obj = diag_i[0].sum() + fz.reg_terms(u, v, cfg.rho, 1.0)
         elif track:
@@ -696,15 +698,15 @@ def _default_cfg(spec, name: str) -> fz.DCFConfig:
 def _record_traffic(cfg: fz.DCFConfig, m: int, num_clients: int,
                     stats: rt.SolveStats) -> None:
     """Feed the process-wide consensus traffic counters (surfaced by
-    ``RPCAService.metrics()``) with this solve's modelled wire bytes."""
+    ``RPCAService.metrics()``) with this solve's modelled wire bytes.
+    The round count stays on the device until the counters are read."""
     from repro.distributed import multihost as mh
 
-    try:
-        rounds = int(np.asarray(stats.rounds).sum())
-    except Exception:  # traced / not yet materialized: use the budget
+    rounds = stats.rounds
+    if isinstance(rounds, jax.core.Tracer):  # traced: count the budget
         rounds = cfg.outer_iters
     mh.record_consensus(m, cfg.rank, num_clients, rounds,
-                        cfg.consensus_compress)
+                        cfg.consensus_compress, budget=cfg.outer_iters)
 
 
 def _registry_make(spec, cfg, run_cfg):
@@ -1133,97 +1135,101 @@ def _build_sharded(
                 n_frac=n_frac_i, eta=eta, reduce_m=reduce_m, w=w_local,
             )
             u_i, pt, v_keep = round_gates(tg, u_i, u_used)
-            wsum = None
-            if robust:
-                # One unweighted vote per client: the robust combine is
-                # over raw deltas, weights would let one client scale its
-                # own influence.
-                wgt = jnp.float32(1.0)
-                if v_keep is not None:
-                    v_new = jnp.where(v_keep > 0, v_new, c["v"])
-            elif pt is None and not ragged:
-                wgt = jnp.float32(1.0 / num_clients)
-            else:
-                ptw = jnp.float32(1.0) if pt is None else pt
-                u_i = jnp.where(ptw > 0, u_i, u_used)
-                if v_keep is not None:
-                    v_new = jnp.where(v_keep > 0, v_new, c["v"])
-                raw_w = ptw * n_i
-                wsum = jax.lax.psum(raw_w, data_axes)
-                wgt = raw_w / jnp.maximum(wsum, 1e-30)
-            contrib = (wgt * (u_i - u_used)).astype(jnp.float32)
-            act = jnp.float32(1.0) if pt is None else pt
-            out = dict(c)
-            if compress is None:
-                if robust or screen is not None:
-                    # Dense robust/screened consensus via the aggregator
-                    # dispatch (RPCA-R006); applied delta-form so the
-                    # delay/pending machinery composes unchanged.
-                    u_cand, wsum = fz.aggregate_sharded(
-                        cfg, u_i, u_used, axes=data_axes, pt=act, n_i=n_i,
-                        uniform=False, reduce_m=reduce_m,
-                    )
-                    delta = (u_cand - u_used).astype(jnp.float32)
-                else:
-                    delta = jax.lax.psum(contrib, data_axes)
-            else:
-                # Wire-compact collective: one all-gather of the compact
-                # (k values, k int32 indices) payloads over the data axes
-                # -- E k * 8 bytes on the wire instead of the dense
-                # m r * 4 all-reduce -- and a deterministic scatter-add,
-                # identical on every shard (lock-step preserved).  Each
-                # model-axis shard compresses its own row block.
-                k = mh_topk_k(u_used.size, compress.topk_frac)
+            with jax.named_scope(fz.CONSENSUS_SCOPE):
+                wsum = None
                 if robust:
-                    # Same wire format, robust receive: per-client
-                    # reconstructions are combined one-vote instead of
-                    # scatter-summed; a poisoned payload must not poison
-                    # the error-feedback carry forever, so non-finite
-                    # residuals reset to zero.
-                    delta, err_new, cnt = gcomp.compressed_consensus_robust(
-                        contrib, data_axes, k, c["err"], active=act,
-                        aggregator=cfg.aggregator, trim_frac=cfg.trim_frac,
-                        screen=screen, reduce_m=reduce_m,
+                    # One unweighted vote per client: the robust combine is
+                    # over raw deltas, weights would let one client scale its
+                    # own influence.
+                    wgt = jnp.float32(1.0)
+                    if v_keep is not None:
+                        v_new = jnp.where(v_keep > 0, v_new, c["v"])
+                elif pt is None and not ragged:
+                    wgt = jnp.float32(1.0 / num_clients)
+                else:
+                    ptw = jnp.float32(1.0) if pt is None else pt
+                    u_i = jnp.where(ptw > 0, u_i, u_used)
+                    if v_keep is not None:
+                        v_new = jnp.where(v_keep > 0, v_new, c["v"])
+                    raw_w = ptw * n_i
+                    wsum = jax.lax.psum(raw_w, data_axes)
+                    wgt = raw_w / jnp.maximum(wsum, 1e-30)
+                contrib = (wgt * (u_i - u_used)).astype(jnp.float32)
+                act = jnp.float32(1.0) if pt is None else pt
+                out = dict(c)
+                if compress is None:
+                    if robust or screen is not None:
+                        # Dense robust/screened consensus via the aggregator
+                        # dispatch (RPCA-R006); applied delta-form so the
+                        # delay/pending machinery composes unchanged.
+                        u_cand, wsum = fz.aggregate_sharded(
+                            cfg, u_i, u_used, axes=data_axes, pt=act, n_i=n_i,
+                            uniform=False, reduce_m=reduce_m,
+                        )
+                        delta = (u_cand - u_used).astype(jnp.float32)
+                    else:
+                        delta = jax.lax.psum(contrib, data_axes)
+                else:
+                    # Wire-compact collective: one all-gather of the compact
+                    # (k values, k int32 indices) payloads over the data axes
+                    # -- E k * 8 bytes on the wire instead of the dense
+                    # m r * 4 all-reduce -- and a deterministic scatter-add,
+                    # identical on every shard (lock-step preserved).  Each
+                    # model-axis shard compresses its own row block.
+                    k = mh_topk_k(u_used.size, compress.topk_frac)
+                    if robust:
+                        # Same wire format, robust receive: per-client
+                        # reconstructions are combined one-vote instead of
+                        # scatter-summed; a poisoned payload must not poison
+                        # the error-feedback carry forever, so non-finite
+                        # residuals reset to zero.
+                        delta, err_new, cnt = (
+                            gcomp.compressed_consensus_robust(
+                                contrib, data_axes, k, c["err"], active=act,
+                                aggregator=cfg.aggregator,
+                                trim_frac=cfg.trim_frac, screen=screen,
+                                reduce_m=reduce_m,
+                            ))
+                        wsum = cnt.astype(jnp.float32)
+                        err_new = jnp.where(jnp.isfinite(err_new),
+                                            err_new, 0.0)
+                    else:
+                        delta, err_new = gcomp_sum(
+                            contrib, data_axes, k, c["err"], active=pt)
+                    out["err"] = err_new
+                if delay == 0:
+                    u_new = u_used + delta
+                    # All-dropout round: delta is an exact zero (every weight
+                    # is 0 / every payload shipped zeros), so u_new == c.u.
+                else:
+                    # Staleness guard: the fused epilogue's ||Psi||_F^2 psum
+                    # (free since PR 5) -- or the consensus-step energy when
+                    # fused="off" -- trips a sticky fallback to synchronous
+                    # application on divergence.  Both scalars are psum/
+                    # reduce_m-composed, so every shard agrees and the
+                    # collectives stay lock-step.
+                    if diag_i is not None and fault_rep is None:
+                        scalar = jax.lax.psum(diag_i[1], all_axes)
+                    else:
+                        # Fault rounds guard on the *applied* delta energy: the
+                        # fused epilogue measured the uncorrupted local run and
+                        # would never see an injected payload blow-up.
+                        scalar = reduce_m(jnp.sum(delta * delta))
+                    # Trip on guard-factor growth OR a non-finite scalar (NaN
+                    # compares False, so the growth test alone never fires on
+                    # a hard blowup).
+                    trip = jnp.logical_or(
+                        ~jnp.isfinite(scalar),
+                        jnp.isfinite(c["guard"])
+                        & (scalar > cfg.stale_guard * c["guard"]),
                     )
-                    wsum = cnt.astype(jnp.float32)
-                    err_new = jnp.where(jnp.isfinite(err_new), err_new, 0.0)
-                else:
-                    delta, err_new = gcomp_sum(
-                        contrib, data_axes, k, c["err"], active=pt)
-                out["err"] = err_new
-            if delay == 0:
-                u_new = u_used + delta
-                # All-dropout round: delta is an exact zero (every weight
-                # is 0 / every payload shipped zeros), so u_new == c.u.
-            else:
-                # Staleness guard: the fused epilogue's ||Psi||_F^2 psum
-                # (free since PR 5) -- or the consensus-step energy when
-                # fused="off" -- trips a sticky fallback to synchronous
-                # application on divergence.  Both scalars are psum/
-                # reduce_m-composed, so every shard agrees and the
-                # collectives stay lock-step.
-                if diag_i is not None and fault_rep is None:
-                    scalar = jax.lax.psum(diag_i[1], all_axes)
-                else:
-                    # Fault rounds guard on the *applied* delta energy: the
-                    # fused epilogue measured the uncorrupted local run and
-                    # would never see an injected payload blow-up.
-                    scalar = reduce_m(jnp.sum(delta * delta))
-                # Trip on guard-factor growth OR a non-finite scalar (NaN
-                # compares False, so the growth test alone never fires on
-                # a hard blowup).
-                trip = jnp.logical_or(
-                    ~jnp.isfinite(scalar),
-                    jnp.isfinite(c["guard"])
-                    & (scalar > cfg.stale_guard * c["guard"]),
-                )
-                sync = jnp.logical_or(c["sync"], trip)
-                u_new = u_used + c["pending"] + jnp.where(
-                    sync, delta, jnp.zeros_like(delta))
-                out["pending"] = jnp.where(sync, jnp.zeros_like(delta),
-                                           delta)
-                out["sync"] = sync
-                out["guard"] = scalar
+                    sync = jnp.logical_or(c["sync"], trip)
+                    u_new = u_used + c["pending"] + jnp.where(
+                        sync, delta, jnp.zeros_like(delta))
+                    out["pending"] = jnp.where(sync, jnp.zeros_like(delta),
+                                               delta)
+                    out["sync"] = sync
+                    out["guard"] = scalar
             if not track:
                 obj = jnp.zeros((), jnp.float32)
             elif (diag_i is not None and sched_rep is None
@@ -1277,6 +1283,7 @@ def _build_sharded(
                 else:
                     c0 = _Carry(u=c0.u, v=c0.v, diag=dg)
 
+            @rt.in_round
             def seg_body(c, t):
                 c = (wire_step if wire else plain_step)((u, v), c, t)
                 return c, (c["diag"] if wire else c.diag)

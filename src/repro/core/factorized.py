@@ -65,6 +65,13 @@ Array = jax.Array
 #: (objective data term, ||Psi||_F^2) measured in a fused pass's epilogue.
 RoundDiag = tuple[Array, Array]
 
+#: Name scopes of a solve's phases in the compiled programs (the round
+#: itself is ``runtime.ROUND_SCOPE``): a profiler trace attributes each
+#: device operation to the phase it was traced in.  Op metadata only.
+CALIBRATE_SCOPE = "rpca.calibrate"  # robust_lam's medians
+RIDGE_SCOPE = "rpca.ridge"  # the r x r Grams, factorizations and solves
+CONSENSUS_SCOPE = "rpca.consensus"  # aggregation of the client factors
+
 
 @dataclass(frozen=True)
 class DCFConfig:
@@ -264,6 +271,8 @@ def _masked_median(x: Array, keep: Array, count: Array) -> Array:
     return 0.5 * (xs[(count - 1) // 2] + xs[count // 2])
 
 
+@partial(jax.jit, static_argnames=("mult", "sample"))
+@jax.named_scope(CALIBRATE_SCOPE)
 def robust_lam(m_obs: Array, mult: float = 2.0,
                mask: Array | None = None,
                sample: int | None = None) -> Array:
@@ -287,6 +296,10 @@ def robust_lam(m_obs: Array, mult: float = 2.0,
     a ~64k-entry subsample estimates the MAD to well under a percent,
     far inside the slack the threshold already tolerates.  ``None`` keeps
     the exact (bit-identical to ``jnp.median``) behavior.
+
+    Runs as one jitted program under :data:`CALIBRATE_SCOPE`, so eager
+    callers (the sharded engine, the serving lanes) are named on the
+    device too; inside a caller's jit it is inlined.
     """
     if mask is not None and bitmask.is_packed(mask):
         mask = bitmask.unpack_mask(mask, m_obs.shape[-1])
@@ -347,6 +360,7 @@ def consensus_weights(n_cols: Array | None, part: Array | None,
 # PR-3 consensus op-for-op (bit-exactness is test-pinned).
 
 
+@jax.named_scope(CONSENSUS_SCOPE)
 def aggregate_stacked(
     cfg: DCFConfig,
     u_i: Array,
@@ -409,6 +423,7 @@ def aggregate_stacked(
     return u, wsum
 
 
+@jax.named_scope(CONSENSUS_SCOPE)
 def aggregate_sharded(
     cfg: DCFConfig,
     u_i: Array,
@@ -565,10 +580,12 @@ def _altmin_ctx(u: Array, rho: float, reduce_m=_identity):
     (ridge back-substitution against the once-factored ``G + rho I``).
     Shared by :func:`inner_solve_altmin` and the fused dual round so the
     Gram gemm / psum / Cholesky run once per local iteration."""
-    g = reduce_m(core_ops.matmul(u.T, u))  # (r, r)
-    g_reg = g + rho * jnp.eye(g.shape[0], dtype=g.dtype)
-    cho = jax.scipy.linalg.cho_factor(g_reg)
+    with jax.named_scope(RIDGE_SCOPE):
+        g = reduce_m(core_ops.matmul(u.T, u))  # (r, r)
+        g_reg = g + rho * jnp.eye(g.shape[0], dtype=g.dtype)
+        cho = jax.scipy.linalg.cho_factor(g_reg)
 
+    @jax.named_scope(RIDGE_SCOPE)
     def update(v: Array, contr: Array) -> Array:
         return jax.scipy.linalg.cho_solve(
             cho, core_ops.matmul(g, v.T) + contr.T).T
@@ -619,15 +636,17 @@ def _u_step(cfg: DCFConfig, u_i: Array, v_i: Array, psi_v: Array,
     else:
         # For fixed (V, S) the U-subproblem is quadratic with Hessian
         # H = V^T V + rho (n_i/n) I  (r x r, local -- no collective).
-        gram_v = core_ops.matmul(v_i.T, v_i)
-        if cfg.precondition == "newton":
-            h = gram_v + n_frac * cfg.rho * jnp.eye(
-                gram_v.shape[0], dtype=gram_v.dtype
-            )
-            upd = eta * jnp.linalg.solve(h, grad_u.T).T
-        else:  # "lipschitz": eta / L with L = sigma_max(V)^2 + rho n_i/n
-            lip = core_ops.spectral_norm_ub_gram(gram_v) + n_frac * cfg.rho
-            upd = (eta / lip) * grad_u
+        with jax.named_scope(RIDGE_SCOPE):
+            gram_v = core_ops.matmul(v_i.T, v_i)
+            if cfg.precondition == "newton":
+                h = gram_v + n_frac * cfg.rho * jnp.eye(
+                    gram_v.shape[0], dtype=gram_v.dtype
+                )
+                upd = eta * jnp.linalg.solve(h, grad_u.T).T
+            else:  # "lipschitz": eta / L, L = sigma_max(V)^2 + rho n_i/n
+                lip = (core_ops.spectral_norm_ub_gram(gram_v)
+                       + n_frac * cfg.rho)
+                upd = (eta / lip) * grad_u
     return u_i - upd
 
 

@@ -213,6 +213,18 @@ def tree_where(pred: Array, new: Any, old: Any) -> Any:
     )
 
 
+#: Name scope of one solver round in the compiled programs: a profiler
+#: trace attributes every device operation traced inside it to the round.
+ROUND_SCOPE = "rpca.round"
+
+
+def in_round(fn: Callable) -> Callable:
+    """``fn`` traced under :data:`ROUND_SCOPE` -- wraps every loop body
+    that runs one round (the drivers here, the serving tick, the sharded
+    engine's segments).  Op metadata only: the computation is unchanged."""
+    return jax.named_scope(ROUND_SCOPE)(fn)
+
+
 def _converged(run: RunConfig, diag: Diag, prev_obj: Array) -> Array:
     if run.criterion == "rel_residual":
         return diag.residual <= run.tol
@@ -337,6 +349,7 @@ def run_segmented(
         return solver.init(problem)
 
     def _segment(problem, carry, ts):
+        @in_round
         def body(c, t):
             c = solver.step(problem, c, t)
             return c, solver.diagnostics(problem, c)
@@ -390,6 +403,7 @@ def run_segmented(
 
 
 def _run_scan(solver, problem, carry0, max_iters, run_cfg):
+    @in_round
     def body(c, t):
         c = solver.step(problem, c, t)
         return c, solver.diagnostics(problem, c)
@@ -422,6 +436,7 @@ def _run_while(solver, problem, carry0, max_iters, run_cfg):
         done = _converged(run_cfg, last, prev_obj) & (t >= run_cfg.min_iters)
         return (t < max_iters) & ~done
 
+    @in_round
     def body(st):
         c, t, last, prev_obj, obuf, rbuf = st
         c = solver.step(problem, c, t)
@@ -462,6 +477,7 @@ def _run_chunk(solver, problem, carry0, max_iters, run_cfg):
     def body(st):
         c, t, last, prev_obj, obuf, rbuf = st
 
+        @in_round
         def inner(cc, i):
             g = t + i
             c_new = solver.step(problem, cc, g)
@@ -538,6 +554,7 @@ def solve_batch(
         _, t, done, *_ = st
         return (t < max_iters) & ~jnp.all(done)
 
+    @in_round
     def body(st):
         c, t, done, rounds, last, prev_obj, obuf, rbuf = st
         c_new = step_b(problems, c, t)

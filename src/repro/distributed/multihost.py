@@ -332,17 +332,47 @@ _TRAFFIC = {
     "shipped_bytes": 0.0,
     "dense_bytes": 0.0,
 }
+#: Recorded solves not yet folded into ``_TRAFFIC``: ``(rounds, budget,
+#: shipped bytes a round, dense bytes a round)``, where ``rounds`` may be
+#: the solve's own device array, still being computed.
+_PENDING: list[tuple[object, int, float, float]] = []
+#: Pending solves that trigger a fold at the next record (bounds the list
+#: in a process that never reads the counters).
+_PENDING_CAP = 1024
 
 
-def record_consensus(m: int, rank: int, num_clients: int, rounds: int,
-                     compress=None) -> None:
-    """Fold one solve's modelled consensus traffic into the counters."""
+def record_consensus(m: int, rank: int, num_clients: int, rounds,
+                     compress=None, budget: int = 0) -> None:
+    """Fold one solve's modelled consensus traffic into the counters.
+
+    ``rounds`` is the solve's executed round count: an int, or its
+    ``SolveStats.rounds`` array (one entry a problem when batched).  The
+    array is kept as it is and summed when the counters are read, so
+    recording never waits for the device.  ``budget`` is counted instead
+    where the array cannot be read back (another process's shards).
+    """
     model = consensus_wire_model(m, rank, num_clients, compress)
     with _traffic_lock:
+        if len(_PENDING) >= _PENDING_CAP:
+            _fold()
         _TRAFFIC["solves"] += 1
-        _TRAFFIC["rounds"] += int(rounds)
-        _TRAFFIC["shipped_bytes"] += model["shipped_bytes"] * rounds
-        _TRAFFIC["dense_bytes"] += model["dense_bytes"] * rounds
+        _PENDING.append((rounds, budget, model["shipped_bytes"],
+                         model["dense_bytes"]))
+
+
+def _fold() -> None:
+    """Sum the pending solves' rounds into ``_TRAFFIC`` (lock held)."""
+    import numpy as np
+
+    for rounds, budget, shipped, dense in _PENDING:
+        try:
+            n = int(np.asarray(rounds).sum())
+        except RuntimeError:  # not addressable from this process
+            n = budget
+        _TRAFFIC["rounds"] += n
+        _TRAFFIC["shipped_bytes"] += shipped * n
+        _TRAFFIC["dense_bytes"] += dense * n
+    _PENDING.clear()
 
 
 def consensus_traffic(reset: bool = False) -> dict[str, float]:
@@ -350,9 +380,11 @@ def consensus_traffic(reset: bool = False) -> dict[str, float]:
 
     ``bytes_per_round`` is the modelled per-client shipped bytes
     averaged over recorded rounds; ``achieved_ratio`` the realized
-    dense/shipped compression (1.0 when every solve ran dense).
+    dense/shipped compression (1.0 when every solve ran dense).  Reading
+    waits for the recorded solves' round counts.
     """
     with _traffic_lock:
+        _fold()
         snap = dict(_TRAFFIC)
         if reset:
             for key in _TRAFFIC:

@@ -136,6 +136,7 @@ class _Lane:
             plane.  The lane keeps ticking for everyone else.
             """
 
+            @rt.in_round
             def body(st, _):
                 carry, t, done, rounds, hit, dived = st
                 adv = lane_active & ~done
