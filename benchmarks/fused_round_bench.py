@@ -29,8 +29,7 @@ Three metric families per path:
 * ``e2e_ms``            end-to-end refresh-style solve (20 rounds incl.
                         problem construction): the fused path also
                         calibrates lam on a 64k-entry subsample instead of
-                        two full-matrix sorts, which dominates short
-                        serving solves.
+                        two full-matrix medians.
 
 Quality gates ride along: the f32 fused kernels are bit-exact vs the
 unfused ref oracles (asserted here), and the bf16 path's recovery error
@@ -150,7 +149,7 @@ def run(m=2048, n=2048, rank=64, clients=4, observed=0.7):
 
     def e2e(cfg, mat, lam_sample):
         # The compact path calibrates lam on a ~64k-entry strided
-        # subsample instead of two full-matrix sorts (DCFConfig.lam_sample
+        # subsample instead of two full-matrix medians (DCFConfig.lam_sample
         # -- inside the timed program, so both sides pay their own
         # calibration).
         cfg = dataclasses.replace(

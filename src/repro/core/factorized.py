@@ -55,6 +55,7 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import ops as core_ops
 from repro.kernels import bitmask
@@ -125,9 +126,9 @@ class DCFConfig:
     # round-trips any 0/1 mask bit-for-bit.
     pack_mask: bool = False
     # lam calibration subsample: cap the entries fed to robust_lam's
-    # medians (None = exact, two full-matrix sorts).  ~64k (1 << 16)
-    # estimates the MAD to well under a percent -- the right trade for
-    # short refresh/serving solves where calibration would dominate.
+    # medians (None = exact over every entry, by selection: about 70
+    # streaming passes of the plane).  ~64k (1 << 16) estimates the MAD
+    # to well under a percent.
     lam_sample: int | None = None
     # Communication-optimal consensus wire (DESIGN.md Sec. 14).  With a
     # CompressConfig (its ``topk_frac`` must be set), each client ships
@@ -271,6 +272,72 @@ def _masked_median(x: Array, keep: Array, count: Array) -> Array:
     return 0.5 * (xs[(count - 1) // 2] + xs[count // 2])
 
 
+_I32_MIN = -(1 << 31)
+_I32_MAX = (1 << 31) - 1
+_NAN_KEY = 0x7FC00000  # key of the canonical (positive quiet) NaN
+
+
+def _order_key(x: Array) -> Array:
+    """int32 key of each f32 entry that orders as ``lax.sort`` orders the
+    floats: zeros canonicalized as its comparator does (``x == 0``, so a
+    flushed denormal ties too), every NaN above ``+inf``
+    (``lax._canonicalize_float_for_sort``).  Negative floats' bit
+    patterns are flipped onto ``[INT32_MIN + 1, -1]``."""
+    x = jnp.where(x == 0, 0.0, x)
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = jnp.where(b < 0, _I32_MIN - b, b)
+    return jnp.where(jnp.isnan(x), _NAN_KEY, key)
+
+
+def _key_value(key: Array) -> Array:
+    """The f32 whose :func:`_order_key` is ``key`` (zeros come back +0)."""
+    b = jnp.where(key < 0, _I32_MIN - key, key)
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
+def _select_key(x: Array, k) -> Array:
+    """Key of the ``k``-th smallest (0-based) entry of the f32 ``x``:
+    bisection over the int32 key range, 32 fused count passes, no sort."""
+    def step(_, bounds):
+        lo, hi = bounds
+        mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)  # floor mean, no overflow
+        over = jnp.sum(_order_key(x) <= mid, dtype=jnp.int32) > k
+        return jnp.where(over, lo, mid + 1), jnp.where(over, mid, hi)
+
+    lo, _ = jax.lax.fori_loop(
+        0, 32, step, (jnp.int32(_I32_MIN), jnp.int32(_I32_MAX)))
+    return lo
+
+
+def _select_median(x: Array, k_lo, k_hi) -> Array:
+    """``0.5 * (x_(k_lo) + x_(k_hi))`` over the f32 ``x`` in sort order,
+    with ``k_hi`` equal to ``k_lo`` or one above it.  The upper rank costs
+    one pass: it shares ``k_lo``'s value when more than ``k_hi`` entries
+    lie at or below it, else it is the least key above."""
+    key_lo = _select_key(x, k_lo)
+    key = _order_key(x)
+    shared = jnp.sum(key <= key_lo, dtype=jnp.int32) > k_hi
+    above = jnp.min(jnp.where(key > key_lo, key, _I32_MAX))
+    key_hi = jnp.where(shared, key_lo, above)
+    return 0.5 * (_key_value(key_lo) + _key_value(key_hi))
+
+
+def _midpoint_ranks(n: int) -> tuple[int, int]:
+    """The two ranks ``jnp.median`` averages over ``n`` entries: its
+    ``quantile(method="midpoint")`` forms the index in f32, so
+    ``q = 0.5 * (f32(n) - 1)`` rounds (at n = 2**26 it takes the single
+    rank 2**25, not the integer midpoint of 2**25 - 1 and 2**25)."""
+    q = np.float32(0.5) * (np.float32(n) - np.float32(1))
+    return int(np.floor(q)), int(np.ceil(q))  # within jnp's [0, n - 1] clamp
+
+
+def _exact_median(x: Array) -> Array:
+    """``jnp.median`` of the f32 ``x`` by selection: the same bits, up to
+    the sign of a zero median (which ``|x - med|`` does not see)."""
+    med = _select_median(x, *_midpoint_ranks(x.size))
+    return jnp.where(jnp.any(jnp.isnan(x)), jnp.nan, med)
+
+
 @partial(jax.jit, static_argnames=("mult", "sample"))
 @jax.named_scope(CALIBRATE_SCOPE)
 def robust_lam(m_obs: Array, mult: float = 2.0,
@@ -290,12 +357,13 @@ def robust_lam(m_obs: Array, mult: float = 2.0,
     entries are stored as zeros and would otherwise drag the MAD toward 0.
     A bit-packed uint8 mask is accepted and unpacked.
 
-    ``sample`` caps the number of entries fed to the medians (strided
-    subsample).  Exact medians cost two full sorts -- on large matrices
-    that dwarfs the per-round work (XLA sorts are slow on every backend);
-    a ~64k-entry subsample estimates the MAD to well under a percent,
-    far inside the slack the threshold already tolerates.  ``None`` keeps
-    the exact (bit-identical to ``jnp.median``) behavior.
+    Both medians are exact order statistics found by selection (about 35
+    streaming count passes each, no sort): bit-identical to ``jnp.median``
+    unmasked and to :func:`_masked_median` masked, including their
+    ``-0.0``, infinity and NaN behavior.  ``sample`` caps the number of
+    entries fed to the medians (strided subsample): a ~64k-entry subsample
+    estimates the MAD to well under a percent.  ``None`` keeps the exact
+    MAD of every entry.
 
     Runs as one jitted program under :data:`CALIBRATE_SCOPE`, so eager
     callers (the sharded engine, the serving lanes) are named on the
@@ -304,8 +372,9 @@ def robust_lam(m_obs: Array, mult: float = 2.0,
     if mask is not None and bitmask.is_packed(mask):
         mask = bitmask.unpack_mask(mask, m_obs.shape[-1])
     n_cols = m_obs.shape[-1] if m_obs.ndim >= 2 else 1
-    x = m_obs.ravel().astype(jnp.float32)
-    keep = None if mask is None else mask.ravel() > 0
+    # Kept in its own shape: a ravel would copy the tiled plane.
+    x = m_obs.astype(jnp.float32)
+    keep = None if mask is None else mask > 0
     if sample is not None and x.size > sample:
         stride = -(-x.size // sample)
         # A stride sharing a factor with the column count would walk only
@@ -316,14 +385,17 @@ def robust_lam(m_obs: Array, mult: float = 2.0,
 
         while n_cols > 1 and math.gcd(stride, n_cols) > 1:
             stride += 1
-        x = x[::stride]
-        keep = None if keep is None else keep[::stride]
+        x = x.ravel()[::stride]
+        keep = None if keep is None else keep.ravel()[::stride]
     if keep is None:
-        med = jnp.median(x)
-        return mult * 1.4826 * jnp.median(jnp.abs(x - med))
+        med = _exact_median(x)
+        return mult * 1.4826 * _exact_median(jnp.abs(x - med))
+    # _masked_median's ranks and +inf fill for the hidden entries.
     count = jnp.maximum(jnp.sum(keep.astype(jnp.int32)), 1)
-    med = _masked_median(x, keep, count)
-    return mult * 1.4826 * _masked_median(jnp.abs(x - med), keep, count)
+    k_lo, k_hi = (count - 1) // 2, count // 2
+    med = _select_median(jnp.where(keep, x, jnp.inf), k_lo, k_hi)
+    dev = jnp.where(keep, jnp.abs(x - med), jnp.inf)
+    return mult * 1.4826 * _select_median(dev, k_lo, k_hi)
 
 
 def consensus_weights(n_cols: Array | None, part: Array | None,

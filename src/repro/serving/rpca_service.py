@@ -265,8 +265,7 @@ class RPCAService:
 
         # robust_lam calibration cache: (M fingerprint, mask fingerprint)
         # -> calibrated lam.  Warm refreshes of unchanged tenant data skip
-        # the full-matrix sorts (PR-5: the 20-round refresh e2e is lam-
-        # calibration dominated).
+        # the two full-matrix median selections.
         self._lam_cache: "OrderedDict[tuple, float]" = OrderedDict()
         self._lam_hits = 0
         self._lam_misses = 0
@@ -377,7 +376,7 @@ class RPCAService:
         key = jax.random.fold_in(self._key, self._n_submitted)
         self._n_submitted += 1
         # lam calibration cache: fingerprint the *submitted* (pre-pad)
-        # planes -- only for configs that actually sort the data for lam
+        # planes -- only for configs that take medians of the data for lam
         # (the factorized family with lam=None); the convex lanes derive
         # lam from the shape for free.  ``fp_key`` is remembered per slot
         # (hit or miss) so release() can refcount-evict the entry.
