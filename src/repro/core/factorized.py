@@ -631,9 +631,9 @@ def inner_solve_altmin(
     ``Psi_W = W * clip(M - U V^T, +-lam)``, so masking only changes the
     fused contraction, not the sweep structure.
 
-    The (r, r) system matrix ``G + rho I`` is constant across the sweeps
-    (U is fixed), so it is Cholesky-factored once outside the scan and
-    each sweep back-substitutes (``cho_solve``) instead of re-factorizing.
+    The (r, r) system matrix ``A = G + rho I`` is constant across the
+    sweeps (U is fixed), so its inverse is formed once outside the scan
+    and each sweep is one (n_i, r) x (r, r) product (:func:`_altmin_ctx`).
     """
     g, update = _altmin_ctx(u, rho, reduce_m)
 
@@ -648,19 +648,32 @@ def inner_solve_altmin(
 
 
 def _altmin_ctx(u: Array, rho: float, reduce_m=_identity):
-    """Per-U altmin context: the Gram matrix and a one-sweep V update
-    (ridge back-substitution against the once-factored ``G + rho I``).
-    Shared by :func:`inner_solve_altmin` and the fused dual round so the
-    Gram gemm / psum / Cholesky run once per local iteration."""
+    """Per-U altmin context: the Gram matrix and a one-sweep V update.
+
+    ``A = G + rho I`` is inverted once per U -- one Cholesky factor
+    ``A = L L^T`` and one triangular solve for ``L^{-1}``, then
+    ``A^{-1} = L^{-T} L^{-1}`` -- and each sweep applies it as a product,
+    ``V^T + A^{-1} (C^T - rho V^T)``, which equals
+    ``A^{-1} (G V^T + C^T)`` with ``C = Psi^T U`` (the sweep's
+    contraction) and needs no ``G V^T``.  No sweep factors or
+    back-substitutes: on the TPU a triangular solve inverts the factor's
+    r x r diagonal block in one custom call, whose cost hardly depends
+    on the width of the right-hand side, so it runs once per U instead
+    of once per sweep.  Shared by :func:`inner_solve_altmin`
+    and the fused dual round so the Gram gemm / psum / inverse run once
+    per local iteration."""
     with jax.named_scope(RIDGE_SCOPE):
         g = reduce_m(core_ops.matmul(u.T, u))  # (r, r)
-        g_reg = g + rho * jnp.eye(g.shape[0], dtype=g.dtype)
-        cho = jax.scipy.linalg.cho_factor(g_reg)
+        eye = jnp.eye(g.shape[0], dtype=g.dtype)
+        chol = jax.lax.linalg.cholesky(g + rho * eye,
+                                       symmetrize_input=False)
+        chol_inv = jax.lax.linalg.triangular_solve(
+            chol, eye, left_side=True, lower=True)
+        a_inv = core_ops.matmul(chol_inv.T, chol_inv)
 
     @jax.named_scope(RIDGE_SCOPE)
     def update(v: Array, contr: Array) -> Array:
-        return jax.scipy.linalg.cho_solve(
-            cho, core_ops.matmul(g, v.T) + contr.T).T
+        return v + core_ops.matmul(contr - rho * v, a_inv)
 
     return g, update
 
