@@ -47,8 +47,9 @@ from repro.core import (
     generate_problem,
     relative_error,
 )
+from repro.core.factorized import topk_k
 from repro.distributed.grad_compress import CompressConfig
-from repro.distributed.multihost import consensus_wire_model, topk_k
+from repro.distributed.multihost import consensus_wire_model
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)

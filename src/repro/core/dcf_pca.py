@@ -1,33 +1,43 @@
 """DCF-PCA -- Algorithm 1: distributed RPCA via consensus factorization.
 
-Two execution engines with identical math:
+One round body, :func:`client_step`, is what one client does in a round:
+its local iterations, the participation/fault gate, the consensus
+(``factorized.aggregate_sharded``, with the optional top-k wire and stale
+application) and the round's objective and residual.  Its collectives
+name a client axis, and two engines map it over their clients:
 
 ``dcf_pca``          Simulated clients on one device: the E column blocks
-                     live on a leading axis and the per-client local round
-                     is ``vmap``-ed; consensus (Eq. 9) is a mean over that
-                     axis.  This reproduces the paper's single-device
-                     simulation exactly and backs all paper experiments.
+                     live on a leading axis and the step runs under
+                     ``jax.vmap(axis_name=CLIENTS)``.  A collective over
+                     the mapped axis reduces over it and returns one
+                     unbatched value, so the consensus ``pmean`` is the
+                     stacked mean bit for bit and the consensus U, the
+                     residual and the objective are computed once.  This
+                     reproduces the paper's single-device simulation and
+                     backs all paper experiments.
 
 ``dcf_pca_sharded``  SPMD engine: ``M`` is column-sharded over the mesh's
                      data axes (every shard is one "client") and optionally
-                     row-sharded over the model axis.  The consensus average
-                     is a single ``lax.pmean`` of the (m, r) factor per
-                     round -- the paper's 2 E m r communication bound, run
-                     as a bandwidth-optimal ICI all-reduce.  V_i and S_i
-                     never leave their shard (the privacy property).
+                     row-sharded over the model axis; the step runs under
+                     ``jax.shard_map``.  The consensus average is a single
+                     ``lax.pmean`` of the (m, r) factor per round -- the
+                     paper's 2 E m r communication bound, run as a
+                     bandwidth-optimal ICI all-reduce.  V_i and S_i never
+                     leave their shard (the privacy property).
 
-Both engines run on the unified solver runtime (DESIGN.md Sec. 4): pass
-``run=`` for convergence-controlled or chunked execution and
-``warm=(U, V)`` to seed the factors from a prior solve.  Client topology
-is elastic (DESIGN.md Sec. 10): ``n % num_clients != 0`` zero-pads ragged
-columns behind a mask plane and weights the consensus by true per-client
-counts, and ``participation=`` (a (T, E) 0/1 schedule or Bernoulli rate)
-runs partial-participation rounds -- dropped clients freeze their ``V_i``
-and are excluded from that round's weighted average.  In the sharded
-engine the convergence residual is computed on the *consensus* U (with a
-model-axis psum of the norms when rows are sharded), so the
-``lax.while_loop`` predicate is identical on every shard and the collec-
-tives stay lock-step.
+``grad_compress.consensus_compress`` scans the same step over the
+data-parallel axes of a training step.  Both engines run on the unified
+solver runtime (DESIGN.md Sec. 4): pass ``run=`` for convergence-
+controlled or chunked execution and ``warm=(U, V)`` to seed the factors
+from a prior solve.  Client topology is elastic (DESIGN.md Sec. 10):
+``n % num_clients != 0`` zero-pads ragged columns behind a mask plane and
+weights the consensus by true per-client counts, and ``participation=``
+(a (T, E) 0/1 schedule or Bernoulli rate) runs partial-participation
+rounds -- dropped clients freeze their ``V_i`` and are excluded from that
+round's weighted average.  The convergence residual is computed on the
+*consensus* U (with a model-axis psum of the norms when rows are
+sharded), so a ``lax.while_loop`` predicate is identical on every shard
+and the collectives stay lock-step.
 """
 from __future__ import annotations
 
@@ -45,6 +55,7 @@ from repro.core import problems as prob
 from repro.core import runtime as rt
 from repro.core import validate
 from repro.distributed import faults as flt
+from repro.distributed import multihost as mh
 from repro.kernels import bitmask
 
 Array = jax.Array
@@ -91,370 +102,230 @@ class DCFProblem(NamedTuple):
     faults: Array | None = None  # (T_f, E) int32 fault-code table
 
 
-class _Carry(NamedTuple):
-    u: Array
-    v: Array
-    diag: rt.Diag
+#: Axis name of the simulated engine's clients under ``jax.vmap``.
+CLIENTS = "clients"
+#: Carry leaves each client holds alone; the rest is the consensus state,
+#: the same on every client.
+_CLIENT_LEAVES = ("v", "err")
 
 
-def _inject_round_faults(
-    p: DCFProblem, t: Array, u_i: Array, u_prev: Array
-) -> tuple[Array, Array | None, Array | None]:
-    """Apply round ``t``'s fault codes at the consensus boundary
-    (simulated engine).  Returns ``(u_i, pt, v_mask)``: the possibly
-    corrupted payload stack, the effective participation vector (crash /
-    flaky votes dropped; ``None`` when unconditional) and the V-advance
-    mask (only a crash freezes local state; ``None`` when all advance).
-    """
-    pt = None
-    if p.participation is not None:
-        pt = p.participation[jnp.mod(t, p.participation.shape[0])]
-    if p.faults is None:
+def client_init(cfg: fz.DCFConfig, u: Array, v: Array,
+                num_clients: int | None = None) -> dict:
+    """The round carry: the consensus ``u``, this client's ``v`` and the
+    last round's diagnostics, plus the wire state the config asks for --
+    ``err`` (the top-k residual, one per client), and ``pending``,
+    ``sync`` and ``guard`` (the stale delta and its guard).  A dict, so
+    the extra state rides the runtime's generic pytree plumbing (batch
+    freeze masks via ``tree_where`` included).  ``num_clients`` stacks
+    ``err`` for the simulated engine, whose ``v`` is already stacked."""
+    inf = jnp.asarray(jnp.inf, jnp.float32)
+    c = {"u": u, "v": v, "diag": rt.Diag(inf, inf)}
+    if cfg.consensus_compress is not None:
+        lead = () if num_clients is None else (num_clients,)
+        c["err"] = jnp.zeros(lead + u.shape, jnp.float32)
+    if cfg.consensus_delay:
+        c["pending"] = jnp.zeros(u.shape, jnp.float32)
+        c["sync"] = jnp.zeros((), jnp.bool_)
+        c["guard"] = inf
+    return c
+
+
+def _round_gate(sched: Array | None, faults: Array | None, t: Array, axes,
+                u_i: Array, u_prev: Array):
+    """This client's (payload, consensus weight, V-advance) for round
+    ``t``: the participation schedule composed with the fault plan at the
+    consensus boundary (DESIGN.md Sec. 17).  Both tables are replicated
+    ``(T, E)``; each client reads its own column.  Returns ``(u_i, pt,
+    v_keep)`` with ``pt``/``v_keep`` None on the no-schedule, no-fault
+    path."""
+    if sched is None and faults is None:
+        return u_i, None, None
+    idx = jax.lax.axis_index(axes)
+    pt = (jnp.float32(1.0) if sched is None
+          else sched[jnp.mod(t, sched.shape[0]), idx])
+    if faults is None:
         return u_i, pt, pt
-    code = flt.round_codes(p.faults, t)
+    code = faults[jnp.mod(t, faults.shape[0]), idx]
     u_i = flt.corrupt_payload(code, u_i, u_prev)
-    live = flt.live_mask(code)
-    adv = flt.v_advance_mask(code)
-    if pt is None:
-        return u_i, live, adv
-    return u_i, pt * live, pt * adv
+    # Crash/flaky drop the vote; every fault but a crash ran the local
+    # computation, so V_i advances (a dropped *message* must not freeze
+    # local state).
+    return u_i, pt * flt.live_mask(code), pt * flt.v_advance_mask(code)
+
+
+def client_step(
+    cfg: fz.DCFConfig,
+    c: dict,
+    t: Array,
+    *,
+    block: Array,
+    w: Array | None,
+    n_i: Array | None,
+    lam0: Array | float,
+    t0: Array | int,
+    sched: Array | None,
+    faults: Array | None,
+    axes,
+    track: bool,
+    model_axis: str | None = None,
+) -> dict:
+    """One DCF round (Alg. 1) as one client runs it; its collectives name
+    the client ``axes``.  The simulated engine maps it over its stacked
+    clients with ``jax.vmap(axis_name=CLIENTS)``, the sharded engine runs
+    it under ``jax.shard_map`` over the mesh's data axes, and
+    ``grad_compress.consensus_compress`` scans it over the data-parallel
+    axes.  Consensus results (``u``, the residual, the objective, the
+    wire's pending delta and guard) are the same on every client.
+
+    ``block``/``w`` are this client's columns and mask slice, ``n_i`` its
+    true column count (``None`` on equal blocks: the compile-time share
+    ``1/E`` keeps the plain path bit-exact), ``sched``/``faults`` the
+    replicated participation and fault tables (``None`` = none),
+    ``model_axis`` the sharded engine's row axis (its partial sums are
+    psum-ed over it).  ``t`` is the round within this solve; ``t0``
+    offsets the schedules for warm-started resumes.
+    """
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+    if model_axis is None:
+        reduce_m, all_axes = fz._identity, axes
+    else:
+        reduce_m = partial(jax.lax.psum, axis_name=model_axis)
+        all_axes = axes + (model_axis,)
+    delay = cfg.consensus_delay
+    tg = t + t0
+    eta = cfg.lr(tg)
+    lam_t = cfg.lam_at(lam0, tg)
+    n_frac = (1.0 / jax.lax.axis_size(axes) if n_i is None
+              else n_i / jax.lax.psum(n_i, axes))
+    u_prev = c["u"]
+    u_i, v_new, diag_i = fz.local_round(
+        u_prev, c["v"], block, cfg=cfg, lam=lam_t, n_frac=n_frac, eta=eta,
+        reduce_m=reduce_m, w=w,
+    )
+    u_i, pt, v_keep = _round_gate(sched, faults, tg, axes, u_i, u_prev)
+    if v_keep is not None:
+        # Dropped / crashed this round: the client's V_i freezes (no
+        # decay toward zero weight).
+        v_new = jnp.where(v_keep > 0, v_new, c["v"])
+    out = dict(c)
+    agg = dict(axes=axes, pt=jnp.float32(1.0) if pt is None else pt,
+               n_i=jnp.float32(1.0) if n_i is None else n_i,
+               uniform=pt is None and n_i is None, reduce_m=reduce_m)
+    if cfg.consensus_compress is None:
+        u_new, wsum = fz.aggregate_sharded(cfg, u_i, u_prev, **agg)
+    else:
+        u_new, wsum, out["err"] = fz.aggregate_sharded(
+            cfg, u_i, u_prev, err=c["err"], **agg)
+    if delay:
+        with jax.named_scope(fz.CONSENSUS_SCOPE):
+            # Stale application (DESIGN.md Sec. 14): this round's delta is
+            # parked and applied next round.  The guard scalar is the
+            # fused epilogue's ||Psi||_F^2 or, with fused="off" or under
+            # faults (the epilogue measured the uncorrupted local run),
+            # the consensus-step energy; growth past stale_guard x or a
+            # non-finite value (NaN compares False) trips a sticky
+            # fallback to synchronous application.
+            delta = u_new - u_prev
+            if diag_i is not None and faults is None:
+                scalar = jax.lax.psum(diag_i[1], all_axes)
+            else:
+                scalar = reduce_m(jnp.sum(delta * delta))
+            trip = jnp.logical_or(
+                ~jnp.isfinite(scalar),
+                jnp.isfinite(c["guard"])
+                & (scalar > cfg.stale_guard * c["guard"]),
+            )
+            sync = jnp.logical_or(c["sync"], trip)
+            zero = jnp.zeros_like(delta)
+            u_new = u_prev + c["pending"] + jnp.where(sync, delta, zero)
+            out["pending"] = jnp.where(sync, zero, delta)
+            out["sync"] = sync
+            out["guard"] = scalar
+    if not track:
+        obj = jnp.zeros((), jnp.float32)
+    elif diag_i is not None and sched is None and faults is None:
+        # Fused epilogue data term (already summed over this client's
+        # rows; a model axis holds distinct rows, so the all-axes psum
+        # composes it exactly like local_objective).
+        obj = jax.lax.psum(
+            diag_i[0] + fz.reg_terms(u_new, v_new, cfg.rho, n_frac),
+            all_axes)
+    else:
+        # Participation/fault rounds take a separate pass: a dropped
+        # client's epilogue measured a local run whose factors were then
+        # discarded, so the frozen state's objective is the meaningful one.
+        obj = jax.lax.psum(
+            fz.local_objective(u_new, v_new, block, cfg.rho, lam_t, n_frac,
+                               w=w),
+            all_axes)
+    # Residual on the consensus U (norms psum-ed over the model axis), so
+    # every client sees the same scalar and a while_loop predicate -- and
+    # hence the collectives -- stay lock-step.
+    du2 = reduce_m(jnp.sum((u_new - u_prev) ** 2))
+    u2 = reduce_m(jnp.sum(u_prev**2))
+    resid = jnp.sqrt(du2) / (jnp.sqrt(u2) + 1e-30)
+    if delay:
+        # Round 0 applies nothing (its delta is pending): a zero residual
+        # would read as instant convergence, so re-emit the previous.
+        resid = jnp.where(t > 0, resid, c["diag"].residual)
+    if wsum is not None:
+        # All-dropout round (possible in user-supplied schedules): a no-op
+        # that re-emits the previous residual (zero would read as
+        # convergence) and an *inf* objective ("not measured": the frozen
+        # state would trivially plateau), which suppresses the
+        # obj_plateau check for this round and the next.  wsum is the
+        # same on every client, so an early exit stays lock-step.
+        resid = jnp.where(wsum > 0, resid, c["diag"].residual)
+        if track:
+            obj = jnp.where(wsum > 0, obj, jnp.inf)
+    out["u"] = u_new
+    out["v"] = v_new
+    out["diag"] = rt.Diag(obj, resid)
+    return out
+
+
+def _flushed_u(cfg: fz.DCFConfig, c: dict) -> Array:
+    """The final consensus U: the stale wire's in-flight delta is applied,
+    so the last round's consensus step is not dropped."""
+    return c["u"] + c["pending"] if cfg.consensus_delay else c["u"]
 
 
 # ---------------------------------------------------------------------------
 # Engine 1: simulated clients (paper Sec. 4.1 "Implementation")
 # ---------------------------------------------------------------------------
-def _sim_local_rounds(cfg: fz.DCFConfig, p: DCFProblem, u: Array, v: Array,
-                      eta: Array, lam_t: Array):
-    """Server broadcasts U; clients run K local iterations concurrently
-    (vmapped over the client axis).  Returns ``(u_i, v_new, diag_i,
-    n_frac)`` -- the per-client factor proposals, epilogue diagnostics
-    (None when ``cfg.fused == "off"``) and regularizer shares."""
-    e = p.blocks.shape[0]
-    if p.n_cols is None:
-        # Equal blocks: the compile-time 1/E constant keeps this path
-        # bit-exact with the pre-elastic engine.
-        n_frac = 1.0 / e
-        local = partial(fz.local_round, cfg=cfg, lam=lam_t,
-                        n_frac=n_frac)
-        if p.mask is None:
-            u_i, v_new, diag_i = jax.vmap(
-                lambda vb, mb: local(u, vb, mb, eta=eta)
-            )(v, p.blocks)
-        else:
-            u_i, v_new, diag_i = jax.vmap(
-                lambda vb, mb, wb: local(u, vb, mb, eta=eta, w=wb)
-            )(v, p.blocks, p.mask)
-    else:
-        # Ragged blocks always carry a mask (padding columns are
-        # mask-zero) and a per-client regularizer share n_i/n.
-        n_frac = p.n_cols / jnp.sum(p.n_cols)
-        local = partial(fz.local_round, cfg=cfg, lam=lam_t)
-        u_i, v_new, diag_i = jax.vmap(
-            lambda vb, mb, wb, nf: local(u, vb, mb, eta=eta, w=wb,
-                                         n_frac=nf)
-        )(v, p.blocks, p.mask, n_frac)
-    return u_i, v_new, diag_i, n_frac
+def make_solver(cfg: fz.DCFConfig, *, with_objective: bool = False) -> rt.Solver:
+    """Runtime Solver for the simulated-client engine: :func:`client_step`
+    mapped over the stacked clients, whose collectives reduce over the
+    mapped axis (the consensus is computed once, unbatched)."""
+    track = cfg.track_objective or with_objective
 
+    def init(p: DCFProblem) -> dict:
+        return client_init(cfg, p.u_init, p.v_init, p.v_init.shape[0])
 
-def _sim_objective(cfg: fz.DCFConfig, p: DCFProblem, u: Array, v: Array,
-                   lam_t: Array, n_frac) -> Array:
-    """Legacy (non-epilogue) global objective at the post-consensus state."""
-    if p.n_cols is None:
-        if p.mask is None:
-            return jax.vmap(
-                lambda vb, mb: fz.local_objective(
-                    u, vb, mb, cfg.rho, lam_t, n_frac
-                )
-            )(v, p.blocks).sum()
-        return jax.vmap(
-            lambda vb, mb, wb: fz.local_objective(
-                u, vb, mb, cfg.rho, lam_t, n_frac, w=wb
-            )
-        )(v, p.blocks, p.mask).sum()
-    return jax.vmap(
-        lambda vb, mb, wb, nf: fz.local_objective(
-            u, vb, mb, cfg.rho, lam_t, nf, w=wb
-        )
-    )(v, p.blocks, p.mask, n_frac).sum()
+    def step(p: DCFProblem, c: dict, t: Array) -> dict:
+        # The ``CLIENTS`` scope sits between the map and the step's own
+        # scopes: a transform's name wraps the next name-stack component
+        # (``vmap(rpca.consensus)``), and the trace readers match whole
+        # ``rpca.*`` components.
+        @jax.named_scope(CLIENTS)
+        def mapped(c, block, w, n_i):
+            return client_step(cfg, c, t, block=block, w=w, n_i=n_i,
+                               lam0=p.lam0, t0=p.t0, sched=p.participation,
+                               faults=p.faults, axes=CLIENTS, track=track)
 
+        axes = {k: 0 if k in _CLIENT_LEAVES else None for k in c}
+        return jax.vmap(mapped, in_axes=(axes, 0, 0, 0), out_axes=axes,
+                        axis_name=CLIENTS)(c, p.blocks, p.mask, p.n_cols)
 
-def _sim_finalize(cfg: fz.DCFConfig, p: DCFProblem, u: Array, v: Array):
-    if p.mask is None:
-        l_blocks, s_blocks = jax.vmap(
-            lambda vb, mb: fz.finalize(
-                u, vb, mb, cfg.final_lam(p.lam0), cfg.impl
-            )
-        )(v, p.blocks)
-    else:
+    def finalize(p: DCFProblem, c: dict):
+        u = _flushed_u(cfg, c)
         l_blocks, s_blocks = jax.vmap(
             lambda vb, mb, wb: fz.finalize(
                 u, vb, mb, cfg.final_lam(p.lam0), cfg.impl, w=wb
             )
-        )(v, p.blocks, p.mask)
-    return (
-        prob.merge_columns(l_blocks),
-        prob.merge_columns(s_blocks),
-        u,
-        v,
-    )
-
-
-def make_solver(cfg: fz.DCFConfig, *, with_objective: bool = False) -> rt.Solver:
-    """Runtime Solver for the simulated-client engine."""
-    track = cfg.track_objective or with_objective
-    if cfg.consensus_compress is not None or cfg.consensus_delay:
-        return _make_wire_solver(cfg, track)
-
-    def init(p: DCFProblem) -> _Carry:
-        inf = jnp.asarray(jnp.inf, jnp.float32)
-        return _Carry(u=p.u_init, v=p.v_init, diag=rt.Diag(inf, inf))
-
-    def step(p: DCFProblem, c: _Carry, t: Array) -> _Carry:
-        e = p.blocks.shape[0]
-        t = t + p.t0
-        eta = cfg.lr(t)
-        lam_t = cfg.lam_at(p.lam0, t)
-        # Fused epilogue diagnostics replace the separate objective pass
-        # whenever the fused round measures them; participation/fault
-        # rounds keep the legacy pass (a dropped client's epilogue
-        # measures a local run whose factors are then discarded -- the
-        # frozen state's objective is the meaningful one).
-        fused_obj = (track and cfg.fused != "off"
-                     and p.participation is None and p.faults is None)
-        u_i, v_new, diag_i, n_frac = _sim_local_rounds(
-            cfg, p, c.u, c.v, eta, lam_t
-        )
-        # Consensus boundary: inject the round's faults, then route the
-        # aggregation through the dispatch (RPCA-R006) -- dropped-out /
-        # crashed clients freeze their V_i (no decay toward zero) and are
-        # excluded from the round's consensus; their weight in later
-        # rounds is still the full p_i n_i.
-        u_i, pt, v_mask = _inject_round_faults(p, t, u_i, c.u)
-        v = (v_new if v_mask is None
-             else jnp.where(v_mask[:, None, None] > 0, v_new, c.v))
-        u, wsum = fz.aggregate_stacked(
-            cfg, u_i, c.u, n_cols=p.n_cols, part=pt, num_clients=e
-        )
-        if fused_obj:
-            # Free data terms from the kernel epilogues; only the factor-
-            # norm regularizer is added (sum_i n_frac_i == 1, so the
-            # stacked V and the consensus U take full weight).
-            obj = diag_i[0].sum() + fz.reg_terms(u, v, cfg.rho, 1.0)
-        elif track:
-            obj = _sim_objective(cfg, p, u, v, lam_t, n_frac)
-        else:
-            obj = jnp.zeros((), jnp.float32)
-        resid = jnp.linalg.norm(u - c.u) / (jnp.linalg.norm(c.u) + 1e-30)
-        if wsum is not None:
-            # A user-supplied schedule may contain an all-dropout row
-            # (generated ones never do).  Such a round is a no-op: re-emit
-            # the previous residual -- a zero here would read as
-            # convergence to the rel_residual criterion -- and emit an
-            # *inf* objective ("not measured": the frozen state would
-            # trivially plateau), which suppresses the obj_plateau check
-            # for this round and the next.
-            resid = jnp.where(wsum > 0, resid, c.diag.residual)
-            if track:
-                obj = jnp.where(wsum > 0, obj, jnp.inf)
-        return _Carry(u=u, v=v, diag=rt.Diag(obj, resid))
-
-    def diagnostics(p: DCFProblem, c: _Carry) -> rt.Diag:
-        return c.diag
-
-    def finalize(p: DCFProblem, c: _Carry):
-        return _sim_finalize(cfg, p, c.u, c.v)
-
-    return rt.Solver(init, step, diagnostics, finalize)
-
-
-def _make_wire_solver(cfg: fz.DCFConfig, track: bool) -> rt.Solver:
-    """Simulated-client solver with the consensus *wire* features
-    (DESIGN.md Sec. 14): top-k compressed deltas with error feedback
-    (``cfg.consensus_compress``) and/or one-round stale application
-    (``cfg.consensus_delay``).
-
-    The consensus is reformulated in delta form -- the active-set weights
-    sum to 1, so ``sum_i w_i U_i == U + sum_i w_i (U_i - U)`` -- and the
-    per-client weighted deltas are what crosses the wire.  With
-    compression each client ships only the top-k of its delta plus its
-    error-feedback residual; the dropped remainder stays in the carry and
-    rides the next round's message, so compression error never
-    accumulates (exact when k == m r).  With ``consensus_delay=1`` the
-    round's delta is parked in ``pending`` and applied at the *next*
-    round (overlapping the all-reduce with the next local sweep in the
-    SPMD engine); the fused epilogue's ||Psi||_F^2 scalar guards the
-    staleness -- growth past ``cfg.stale_guard``x trips a sticky fallback
-    to synchronous application.
-
-    The carry is a dict so the extra state rides the runtime's generic
-    pytree plumbing (batch freeze masks via ``tree_where`` included).
-    """
-    from repro.distributed import grad_compress as gcomp
-    from repro.distributed import multihost as mh
-
-    compress = cfg.consensus_compress
-    delay = cfg.consensus_delay
-
-    def init(p: DCFProblem) -> dict:
-        inf = jnp.asarray(jnp.inf, jnp.float32)
-        c = {"u": p.u_init, "v": p.v_init, "diag": rt.Diag(inf, inf)}
-        if compress is not None:
-            c["err"] = jnp.zeros((p.v_init.shape[0],) + p.u_init.shape,
-                                 jnp.float32)
-        if delay:
-            c["pending"] = jnp.zeros(p.u_init.shape, jnp.float32)
-            c["sync"] = jnp.zeros((), jnp.bool_)
-            c["guard"] = inf
-        return c
-
-    robust = cfg.aggregator != "weighted_mean"
-    screen = cfg.divergence_screen
-
-    def step(p: DCFProblem, c: dict, t: Array) -> dict:
-        e = p.blocks.shape[0]
-        tg = t + p.t0
-        eta = cfg.lr(tg)
-        lam_t = cfg.lam_at(p.lam0, tg)
-        fused_obj = (track and cfg.fused != "off"
-                     and p.participation is None and p.faults is None)
-        u_used = c["u"]
-        u_i, v_new, diag_i, n_frac = _sim_local_rounds(
-            cfg, p, u_used, c["v"], eta, lam_t
-        )
-        u_i, pt, v_mask = _inject_round_faults(p, tg, u_i, u_used)
-        v = (v_new if v_mask is None
-             else jnp.where(v_mask[:, None, None] > 0, v_new, c["v"]))
-        with jax.named_scope(fz.CONSENSUS_SCOPE):
-            wsum = None
-            if robust:
-                # One vote per client: unweighted deltas cross the wire; the
-                # robust combine happens on the receive side.
-                w = jnp.ones((e,), jnp.float32)
-            elif pt is None:
-                if p.n_cols is None:
-                    w = jnp.full((e,), 1.0 / e, jnp.float32)
-                else:
-                    w, _ = fz.consensus_weights(p.n_cols, None, e)
-            else:
-                w, wsum = fz.consensus_weights(p.n_cols, pt, e)
-                u_i = jnp.where(pt[:, None, None] > 0, u_i, u_used)
-            # What crosses the wire: each client's weighted delta (their sum
-            # is the consensus step; a dropped client's w is 0, an all-dropout
-            # round sums to an exact no-op).  Robust aggregators ship the
-            # *unweighted* delta and combine one-vote on receive.
-            contrib = (w[:, None, None] * (u_i - u_used)).astype(jnp.float32)
-            out = dict(c)
-            if compress is None:
-                if robust or screen is not None:
-                    act = jnp.ones((e,), jnp.float32) if pt is None else pt
-                    if screen is not None:
-                        act = act * gcomp.divergence_screen_mask(
-                            contrib, act, screen
-                        )
-                    if robust:
-                        delta, cnt = gcomp.robust_combine_stacked(
-                            contrib, act, cfg.aggregator, cfg.trim_frac
-                        )
-                        wsum = cnt.astype(jnp.float32)
-                    else:
-                        # Screened weighted mean: recompute the weights over
-                        # the survivors (contrib already carries the original
-                        # w, so rescale by the survivor renormalization).
-                        w2, wsum = fz.consensus_weights(p.n_cols, act, e)
-                        deltas = (u_i - u_used).astype(jnp.float32)
-                        delta = jnp.sum(
-                            w2[:, None, None]
-                            * jnp.where(act[:, None, None] > 0, deltas, 0.0),
-                            axis=0,
-                        )
-                        delta = jnp.where(wsum > 0, delta, 0.0)
-                else:
-                    delta = contrib.sum(axis=0)
-            else:
-                k = mh.topk_k(u_used.size, compress.topk_frac)
-                flat = (contrib + c["err"]).reshape(e, -1)
-                vals, idx = jax.vmap(lambda x: gcomp.topk_sparsify(x, k))(flat)
-                recon = jax.vmap(
-                    lambda vv, ii: gcomp.topk_reconstruct(vv, ii,
-                                                          flat.shape[1])
-                )(vals, idx)
-                err_new = (flat - recon).reshape(c["err"].shape)
-                if pt is not None:
-                    # Dropped clients ship nothing and keep their residual.
-                    vals = jnp.where(pt[:, None] > 0, vals, 0.0)
-                    err_new = jnp.where(pt[:, None, None] > 0, err_new,
-                                        c["err"])
-                if robust:
-                    # A poisoned payload must not poison its own error-
-                    # feedback carry forever: non-finite residuals reset.
-                    err_new = jnp.where(jnp.isfinite(err_new), err_new, 0.0)
-                    act = jnp.ones((e,), jnp.float32) if pt is None else pt
-                    if screen is not None:
-                        # Judged on the *shipped* payload norms.
-                        nrm = jnp.sqrt(jnp.sum(vals * vals, axis=1))
-                        act = act * gcomp.screen_from_norms(nrm, act, screen)
-                    delta, cnt = gcomp.robust_combine_stacked(
-                        recon.reshape((e,) + u_used.shape), act,
-                        cfg.aggregator, cfg.trim_frac,
-                    )
-                    wsum = cnt.astype(jnp.float32)
-                else:
-                    delta = gcomp.topk_reconstruct(
-                        vals, idx, flat.shape[1]).reshape(u_used.shape)
-                out["err"] = err_new
-            if delay == 0:
-                u = u_used + delta
-            else:
-                # Guard scalar: the fused epilogue's ||Psi||_F^2 (free since
-                # the PR-5 kernels) or, with fused="off", the consensus-step
-                # energy.  Divergence under staleness shows up as growth in
-                # either; the trip is sticky -- once synchronous, stays
-                # synchronous.
-                if diag_i is not None:
-                    scalar = diag_i[1].sum()
-                else:
-                    scalar = jnp.sum(delta * delta)
-                # Trip on guard-factor growth OR a non-finite scalar (a hard
-                # blowup must not slip through: NaN compares False with
-                # everything, so the growth test alone would never fire).
-                trip = jnp.logical_or(
-                    ~jnp.isfinite(scalar),
-                    jnp.isfinite(c["guard"])
-                    & (scalar > cfg.stale_guard * c["guard"]),
-                )
-                sync = jnp.logical_or(c["sync"], trip)
-                u = u_used + c["pending"] + jnp.where(sync, delta,
-                                                      jnp.zeros_like(delta))
-                out["pending"] = jnp.where(sync, jnp.zeros_like(delta), delta)
-                out["sync"] = sync
-                out["guard"] = scalar
-        if fused_obj:
-            obj = diag_i[0].sum() + fz.reg_terms(u, v, cfg.rho, 1.0)
-        elif track:
-            obj = _sim_objective(cfg, p, u, v, lam_t, n_frac)
-        else:
-            obj = jnp.zeros((), jnp.float32)
-        resid = jnp.linalg.norm(u - u_used) / (
-            jnp.linalg.norm(u_used) + 1e-30)
-        if delay:
-            # Round 0 applies nothing (its delta is pending): a zero
-            # residual would read as instant convergence, so re-emit the
-            # previous (inf at init).
-            resid = jnp.where(t > 0, resid, c["diag"].residual)
-        if wsum is not None:
-            resid = jnp.where(wsum > 0, resid, c["diag"].residual)
-            if track:
-                obj = jnp.where(wsum > 0, obj, jnp.inf)
-        out["u"] = u
-        out["v"] = v
-        out["diag"] = rt.Diag(obj, resid)
-        return out
-
-    def finalize(p: DCFProblem, c: dict):
-        # Flush the in-flight delta: the stale pipeline must not drop the
-        # last round's consensus step.
-        u = c["u"] + c["pending"] if delay else c["u"]
-        return _sim_finalize(cfg, p, u, c["v"])
+        )(c["v"], p.blocks, p.mask)
+        return (prob.merge_columns(l_blocks), prob.merge_columns(s_blocks),
+                u, c["v"])
 
     return rt.Solver(init, step, lambda p, c: c["diag"], finalize)
 
@@ -700,8 +571,6 @@ def _record_traffic(cfg: fz.DCFConfig, m: int, num_clients: int,
     """Feed the process-wide consensus traffic counters (surfaced by
     ``RPCAService.metrics()``) with this solve's modelled wire bytes.
     The round count stays on the device until the counters are read."""
-    from repro.distributed import multihost as mh
-
     rounds = stats.rounds
     if isinstance(rounds, jax.core.Tracer):  # traced: count the budget
         rounds = cfg.outer_iters
@@ -909,15 +778,6 @@ def _build_sharded(
     if key is None:
         key = jax.random.PRNGKey(0)
     validate.check_consensus_cfg(cfg, participation)
-    compress = cfg.consensus_compress
-    delay = cfg.consensus_delay
-    wire = compress is not None or bool(delay)
-    if wire:
-        from repro.distributed import grad_compress as gcomp
-        from repro.distributed.grad_compress import (
-            compressed_consensus_sum as gcomp_sum,
-        )
-        from repro.distributed.multihost import topk_k as mh_topk_k
     if cfg.pack_mask and mask is not None:
         # The mask plane is sharded exactly like M (P(model, data)); a
         # packed (m, n/8) plane would need its own sharding layout and
@@ -953,7 +813,6 @@ def _build_sharded(
         base = mask if mask is not None else jnp.ones_like(m_obs)
         mask = jnp.pad(base, ((0, 0), (0, n_pad - n)))
         m_obs = jnp.pad(m_obs, ((0, 0), (0, n_pad - n)))
-    n_frac = 1.0 / num_clients
     sched = _resolve_participation(
         participation, cfg.outer_iters, num_clients, key
     )
@@ -972,13 +831,6 @@ def _build_sharded(
     row_spec = model_axis  # None => replicated rows
     m_sharding = NamedSharding(mesh, P(row_spec, data_axes))
     u_sharding = NamedSharding(mesh, P(row_spec, None))
-
-    reduce_m = (
-        (lambda x: jax.lax.psum(x, model_axis))
-        if model_axis is not None
-        else (lambda x: x)
-    )
-    all_axes = data_axes + ((model_axis,) if model_axis else ())
 
     k_u, k_v = jax.random.split(key)
     scale = 1.0 / float(jnp.sqrt(float(cfg.rank)))
@@ -999,311 +851,57 @@ def _build_sharded(
             v_warm = jnp.pad(v_warm, ((0, n_pad - n), (0, 0)))
         t0 = cfg.outer_iters  # resume, don't restart, the schedules
 
-    def solve_body(m_local_full, u, v, w_local, sched_rep, fault_rep=None,
-                   seg_extra=None):
+    def solve_body(block, u, v, w, sched, faults, seg_extra=None):
         """shard_map body: this shard's (m_loc, n_i) block + its factors.
-        ``w_local`` is this shard's mask slice (None when fully observed);
-        ``sched_rep`` the replicated participation schedule (None = all);
-        ``fault_rep`` the replicated (T_f, E) fault-code table (None =
-        no injection) -- each shard reads its own column; ``seg_extra``
-        the restored non-factor carry leaves in segmented execution."""
-        idx = jax.lax.axis_index(data_axes)  # linear client index
-        robust = cfg.aggregator != "weighted_mean"
-        screen = cfg.divergence_screen
-
-        def round_gates(t, u_i, u_prev):
-            """This shard's (payload, consensus-weight, V-advance) for the
-            round: the participation schedule composed with the fault plan
-            at the consensus boundary (DESIGN.md Sec. 17).  Returns
-            ``(u_i, pt, v_keep)`` with ``pt``/``v_keep`` None on the
-            no-schedule, no-fault path."""
-            pt_s = (
-                sched_rep[jnp.mod(t, sched_rep.shape[0]), idx]
-                if sched_rep is not None
-                else jnp.float32(1.0)
-            )
-            if fault_rep is None:
-                if sched_rep is None:
-                    return u_i, None, None
-                return u_i, pt_s, pt_s
-            code = fault_rep[jnp.mod(t, fault_rep.shape[0]), idx]
-            u_i = flt.corrupt_payload(code, u_i, u_prev)
-            # Crash/flaky drop the vote; every fault but a crash ran the
-            # local computation, so V_i advances (a dropped *message* must
-            # not freeze local state).
-            return u_i, pt_s * flt.live_mask(code), \
-                pt_s * flt.v_advance_mask(code)
+        ``w`` is this shard's mask slice (None when fully observed);
+        ``sched``/``faults`` the replicated participation schedule and
+        (T_f, E) fault-code table (None = none); ``seg_extra`` the
+        restored non-factor carry leaves in segmented execution."""
+        n_i = None
         if ragged:
             # True column count of this shard: the zero-padding sits at the
             # global tail, so shard i really owns clip(n - i*ni, 0, ni).
+            idx = jax.lax.axis_index(data_axes)
             n_i = jnp.clip(
                 jnp.float32(n) - jnp.float32(ni_pad) * idx, 0.0,
                 jnp.float32(ni_pad),
             )
-            n_frac_i = n_i / jnp.float32(n)
+        step = partial(client_step, cfg, block=block, w=w, n_i=n_i,
+                       lam0=lam, t0=t0, sched=sched, faults=faults,
+                       axes=data_axes, track=track, model_axis=model_axis)
+        c0 = client_init(cfg, u, v)
+        if segment is None:
+            solver = rt.Solver(lambda p: c0, lambda p, c, t: step(c, t),
+                               lambda p, c: c["diag"], lambda p, c: None)
+            carry, stats = rt.run(solver, None, cfg.outer_iters, run_cfg)
+            traces = (stats,)
         else:
-            n_i = jnp.float32(1.0)  # uniform weight base
-            n_frac_i = n_frac  # compile-time 1/E: legacy bit-exact path
-
-        def plain_init(p):
-            inf = jnp.asarray(jnp.inf, jnp.float32)
-            return _Carry(u=p[0], v=p[1], diag=rt.Diag(inf, inf))
-
-        def plain_step(p, c, t):
-            t = t + t0
-            eta = cfg.lr(t)
-            lam_t = cfg.lam_at(lam, t)
-            u_i, v_new, diag_i = fz.local_round(
-                c.u, c.v, m_local_full, cfg=cfg, lam=lam_t, n_frac=n_frac_i,
-                eta=eta, reduce_m=reduce_m, w=w_local,
-            )
-            u_i, pt, v_keep = round_gates(t, u_i, c.u)
-            uniform = pt is None and not ragged
-            # Consensus via the aggregator dispatch (machine-enforced:
-            # RPCA-R006 flags any raw mean/pmean reintroduced here).
-            u_new, wsum = fz.aggregate_sharded(
-                cfg, u_i, c.u, axes=data_axes,
-                pt=jnp.float32(1.0) if pt is None else pt, n_i=n_i,
-                uniform=uniform, reduce_m=reduce_m,
-            )
-            if v_keep is not None:
-                # Dropped / crashed this round: the client's V_i freezes
-                # (no decay toward zero weight).
-                v_new = jnp.where(v_keep > 0, v_new, c.v)
-            if not track:
-                obj = jnp.zeros((), jnp.float32)
-            elif (diag_i is not None and sched_rep is None
-                  and fault_rep is None):
-                # Fused epilogue data term (already summed over this
-                # shard's rows; the model axis holds distinct rows, so the
-                # all-axes psum composes it exactly like local_objective).
-                obj = jax.lax.psum(
-                    diag_i[0]
-                    + fz.reg_terms(u_new, v_new, cfg.rho, n_frac_i),
-                    all_axes,
-                )
-            else:
-                # Participation rounds keep the legacy pass: a dropped
-                # shard's epilogue measured a discarded local run.
-                obj = jax.lax.psum(
-                    fz.local_objective(
-                        u_new, v_new, m_local_full, cfg.rho, lam_t,
-                        n_frac_i, w=w_local,
-                    ),
-                    all_axes,
-                )
-            # Residual on the consensus U: psum the squared norms over the
-            # model axis so every shard sees the same scalar and the
-            # while_loop predicate (and hence the collectives) stay
-            # lock-step across the mesh.
-            du2 = reduce_m(jnp.sum((u_new - c.u) ** 2))
-            u2 = reduce_m(jnp.sum(c.u**2))
-            resid = jnp.sqrt(du2) / (jnp.sqrt(u2) + 1e-30)
-            if wsum is not None:
-                # All-dropout round (possible in user-supplied schedules):
-                # a no-op round re-emits the previous residual (zero would
-                # read as convergence) and an inf objective (the frozen
-                # state would trivially plateau); wsum is a psum, so every
-                # shard agrees and the early exit stays lock-step.
-                resid = jnp.where(wsum > 0, resid, c.diag.residual)
-                if track:
-                    obj = jnp.where(wsum > 0, obj, jnp.inf)
-            return _Carry(u=u_new, v=v_new, diag=rt.Diag(obj, resid))
-
-        def wire_init(p):
-            inf = jnp.asarray(jnp.inf, jnp.float32)
-            c = {"u": p[0], "v": p[1], "diag": rt.Diag(inf, inf)}
-            if compress is not None:
-                c["err"] = jnp.zeros(p[0].shape, jnp.float32)
-            if delay:
-                c["pending"] = jnp.zeros(p[0].shape, jnp.float32)
-                c["sync"] = jnp.zeros((), jnp.bool_)
-                c["guard"] = inf
-            return c
-
-        def wire_step(p, c, t):
-            # Consensus-wire variant (DESIGN.md Sec. 14): the consensus is
-            # delta-form -- each shard's weighted delta crosses the wire
-            # (top-k compressed with error feedback when configured) and
-            # may be applied one round late under consensus_delay.
-            tg = t + t0
-            eta = cfg.lr(tg)
-            lam_t = cfg.lam_at(lam, tg)
-            u_used = c["u"]
-            u_i, v_new, diag_i = fz.local_round(
-                u_used, c["v"], m_local_full, cfg=cfg, lam=lam_t,
-                n_frac=n_frac_i, eta=eta, reduce_m=reduce_m, w=w_local,
-            )
-            u_i, pt, v_keep = round_gates(tg, u_i, u_used)
-            with jax.named_scope(fz.CONSENSUS_SCOPE):
-                wsum = None
-                if robust:
-                    # One unweighted vote per client: the robust combine is
-                    # over raw deltas, weights would let one client scale its
-                    # own influence.
-                    wgt = jnp.float32(1.0)
-                    if v_keep is not None:
-                        v_new = jnp.where(v_keep > 0, v_new, c["v"])
-                elif pt is None and not ragged:
-                    wgt = jnp.float32(1.0 / num_clients)
-                else:
-                    ptw = jnp.float32(1.0) if pt is None else pt
-                    u_i = jnp.where(ptw > 0, u_i, u_used)
-                    if v_keep is not None:
-                        v_new = jnp.where(v_keep > 0, v_new, c["v"])
-                    raw_w = ptw * n_i
-                    wsum = jax.lax.psum(raw_w, data_axes)
-                    wgt = raw_w / jnp.maximum(wsum, 1e-30)
-                contrib = (wgt * (u_i - u_used)).astype(jnp.float32)
-                act = jnp.float32(1.0) if pt is None else pt
-                out = dict(c)
-                if compress is None:
-                    if robust or screen is not None:
-                        # Dense robust/screened consensus via the aggregator
-                        # dispatch (RPCA-R006); applied delta-form so the
-                        # delay/pending machinery composes unchanged.
-                        u_cand, wsum = fz.aggregate_sharded(
-                            cfg, u_i, u_used, axes=data_axes, pt=act, n_i=n_i,
-                            uniform=False, reduce_m=reduce_m,
-                        )
-                        delta = (u_cand - u_used).astype(jnp.float32)
-                    else:
-                        delta = jax.lax.psum(contrib, data_axes)
-                else:
-                    # Wire-compact collective: one all-gather of the compact
-                    # (k values, k int32 indices) payloads over the data axes
-                    # -- E k * 8 bytes on the wire instead of the dense
-                    # m r * 4 all-reduce -- and a deterministic scatter-add,
-                    # identical on every shard (lock-step preserved).  Each
-                    # model-axis shard compresses its own row block.
-                    k = mh_topk_k(u_used.size, compress.topk_frac)
-                    if robust:
-                        # Same wire format, robust receive: per-client
-                        # reconstructions are combined one-vote instead of
-                        # scatter-summed; a poisoned payload must not poison
-                        # the error-feedback carry forever, so non-finite
-                        # residuals reset to zero.
-                        delta, err_new, cnt = (
-                            gcomp.compressed_consensus_robust(
-                                contrib, data_axes, k, c["err"], active=act,
-                                aggregator=cfg.aggregator,
-                                trim_frac=cfg.trim_frac, screen=screen,
-                                reduce_m=reduce_m,
-                            ))
-                        wsum = cnt.astype(jnp.float32)
-                        err_new = jnp.where(jnp.isfinite(err_new),
-                                            err_new, 0.0)
-                    else:
-                        delta, err_new = gcomp_sum(
-                            contrib, data_axes, k, c["err"], active=pt)
-                    out["err"] = err_new
-                if delay == 0:
-                    u_new = u_used + delta
-                    # All-dropout round: delta is an exact zero (every weight
-                    # is 0 / every payload shipped zeros), so u_new == c.u.
-                else:
-                    # Staleness guard: the fused epilogue's ||Psi||_F^2 psum
-                    # (free since PR 5) -- or the consensus-step energy when
-                    # fused="off" -- trips a sticky fallback to synchronous
-                    # application on divergence.  Both scalars are psum/
-                    # reduce_m-composed, so every shard agrees and the
-                    # collectives stay lock-step.
-                    if diag_i is not None and fault_rep is None:
-                        scalar = jax.lax.psum(diag_i[1], all_axes)
-                    else:
-                        # Fault rounds guard on the *applied* delta energy: the
-                        # fused epilogue measured the uncorrupted local run and
-                        # would never see an injected payload blow-up.
-                        scalar = reduce_m(jnp.sum(delta * delta))
-                    # Trip on guard-factor growth OR a non-finite scalar (NaN
-                    # compares False, so the growth test alone never fires on
-                    # a hard blowup).
-                    trip = jnp.logical_or(
-                        ~jnp.isfinite(scalar),
-                        jnp.isfinite(c["guard"])
-                        & (scalar > cfg.stale_guard * c["guard"]),
-                    )
-                    sync = jnp.logical_or(c["sync"], trip)
-                    u_new = u_used + c["pending"] + jnp.where(
-                        sync, delta, jnp.zeros_like(delta))
-                    out["pending"] = jnp.where(sync, jnp.zeros_like(delta),
-                                               delta)
-                    out["sync"] = sync
-                    out["guard"] = scalar
-            if not track:
-                obj = jnp.zeros((), jnp.float32)
-            elif (diag_i is not None and sched_rep is None
-                  and fault_rep is None):
-                obj = jax.lax.psum(
-                    diag_i[0]
-                    + fz.reg_terms(u_new, v_new, cfg.rho, n_frac_i),
-                    all_axes,
-                )
-            else:
-                obj = jax.lax.psum(
-                    fz.local_objective(
-                        u_new, v_new, m_local_full, cfg.rho, lam_t,
-                        n_frac_i, w=w_local,
-                    ),
-                    all_axes,
-                )
-            du2 = reduce_m(jnp.sum((u_new - u_used) ** 2))
-            u2 = reduce_m(jnp.sum(u_used**2))
-            resid = jnp.sqrt(du2) / (jnp.sqrt(u2) + 1e-30)
-            if delay:
-                # Round 0 applies nothing (its delta is pending): re-emit
-                # the previous residual instead of a convergence-faking 0.
-                resid = jnp.where(t > 0, resid, c["diag"].residual)
-            if wsum is not None:
-                resid = jnp.where(wsum > 0, resid, c["diag"].residual)
-                if track:
-                    obj = jnp.where(wsum > 0, obj, jnp.inf)
-            out["u"] = u_new
-            out["v"] = v_new
-            out["diag"] = rt.Diag(obj, resid)
-            return out
-
-        if segment is not None:
             # Checkpoint-segmented execution: scan the [t_start, t_start +
             # seg_len) slice of the *global* round sequence from a restored
             # carry -- the per-round math is identical to rt.run's fixed
             # scan, so segment boundaries never perturb the trajectory.
             t_start, seg_len = segment
-            if wire:
-                c0 = wire_init((u, v))
-            else:
-                c0 = plain_init((u, v))
             if seg_extra is not None:
-                dg = rt.Diag(seg_extra["dobj"], seg_extra["dres"])
-                if wire:
-                    for kk in ("err", "pending", "sync", "guard"):
-                        if kk in seg_extra:
-                            c0[kk] = seg_extra[kk]
-                    c0["diag"] = dg
-                else:
-                    c0 = _Carry(u=c0.u, v=c0.v, diag=dg)
+                c0.update({k: x for k, x in seg_extra.items()
+                           if k in c0})
+                c0["diag"] = rt.Diag(seg_extra["dobj"], seg_extra["dres"])
 
             @rt.in_round
             def seg_body(c, t):
-                c = (wire_step if wire else plain_step)((u, v), c, t)
-                return c, (c["diag"] if wire else c.diag)
+                c = step(c, t)
+                return c, c["diag"]
 
             carry, diags = jax.lax.scan(
                 seg_body, c0, t_start + jnp.arange(seg_len)
             )
+            traces = (diags.objective, diags.residual)
             if not seg_final:
-                if wire:
-                    out = dict(carry)
-                    dg = out.pop("diag")
-                else:
-                    out = {"u": carry.u, "v": carry.v}
-                    dg = carry.diag
+                out = dict(carry)
+                dg = out.pop("diag")
                 # The carry crosses segments as replicated host arrays:
                 # gather the column-sharded V into its global layout (the
                 # E blocks concatenate in client-index order).
-                from repro.distributed import grad_compress as _gc
-
-                out["v"] = _gc.gather_clients(
+                out["v"] = fz.gather_clients(
                     out["v"], data_axes
                 ).reshape(n_pad, cfg.rank)
                 if "err" in out:
@@ -1311,43 +909,17 @@ def _build_sharded(
                     # (each shard drops different top-k coordinates):
                     # stack it client-major like V so every client's
                     # residual survives the replicated hand-off.
-                    out["err"] = _gc.gather_clients(
+                    out["err"] = fz.gather_clients(
                         out["err"], data_axes
                     ).reshape(-1, cfg.rank)
                 out["dobj"] = dg.objective
                 out["dres"] = dg.residual
-                return out, diags.objective, diags.residual
-            if wire:
-                u_fin = (carry["u"] + carry["pending"] if delay
-                         else carry["u"])
-                v_fin = carry["v"]
-            else:
-                u_fin, v_fin = carry.u, carry.v
-            l_blk, s_blk = fz.finalize(
-                u_fin, v_fin, m_local_full, cfg.final_lam(lam), cfg.impl,
-                w=w_local,
-            )
-            return (l_blk, s_blk, u_fin, v_fin, diags.objective,
-                    diags.residual)
-        if wire:
-            solver = rt.Solver(wire_init, wire_step,
-                               lambda p, c: c["diag"], lambda p, c: None)
-        else:
-            solver = rt.Solver(plain_init, plain_step,
-                               lambda p, c: c.diag, lambda p, c: None)
-        carry, stats = rt.run(solver, (u, v), cfg.outer_iters, run_cfg)
-        if wire:
-            # Flush the in-flight stale delta; the last consensus step
-            # must not be dropped.
-            u_fin = carry["u"] + carry["pending"] if delay else carry["u"]
-            v_fin = carry["v"]
-        else:
-            u_fin, v_fin = carry.u, carry.v
+                return (out,) + traces
+        u_fin = _flushed_u(cfg, carry)
         l_blk, s_blk = fz.finalize(
-            u_fin, v_fin, m_local_full, cfg.final_lam(lam), cfg.impl,
-            w=w_local,
+            u_fin, carry["v"], block, cfg.final_lam(lam), cfg.impl, w=w,
         )
-        return l_blk, s_blk, u_fin, v_fin, stats
+        return (l_blk, s_blk, u_fin, carry["v"]) + traces
 
     if segment is None:
         specs_out = (
@@ -1373,14 +945,10 @@ def _build_sharded(
         # Mid-solve carry: every leaf leaves the mesh replicated (V is
         # gathered in-body), so each process can lift a full host copy
         # for the checkpoint writer.
-        carry_specs = {"u": P(None, None), "v": P(None, None),
-                       "dobj": P(), "dres": P()}
-        if compress is not None:
-            carry_specs["err"] = P(None, None)  # gathered client-major
-        if delay:
-            carry_specs["pending"] = P(None, None)
-            carry_specs["sync"] = P()
-            carry_specs["guard"] = P()
+        carry_specs = {
+            k: P(None, None) if k in ("u", "v", "err", "pending") else P()
+            for k in _sharded_ckpt_template(cfg)["carry"]
+        }
         specs_out = (carry_specs, P(None), P(None))
     # Pack the (static-keyed) operand dict so the mask x warm combinations
     # share one shard_map body; absent keys are simply not in the pytree.
@@ -1597,15 +1165,11 @@ def _solve_sharded_checkpointed(
 
 def _sharded_ckpt_template(cfg: fz.DCFConfig) -> dict:
     """Structure template for restoring a sharded segment checkpoint
-    (leaf shapes come from the manifest; only the tree shape matters)."""
+    (leaf shapes come from the manifest; only the tree shape matters):
+    the round carry, its diagnostics as ``dobj``/``dres``."""
     z = jnp.zeros((), jnp.float32)
-    carry = {"u": z, "v": z, "dobj": z, "dres": z}
-    if cfg.consensus_compress is not None:
-        carry["err"] = z
-    if cfg.consensus_delay:
-        carry["pending"] = z
-        carry["sync"] = z
-        carry["guard"] = z
+    carry = {k: z for k in client_init(cfg, z, z) if k != "diag"}
+    carry.update(dobj=z, dres=z)
     return {"carry": carry, "objective": z, "residual": z}
 
 

@@ -8,6 +8,10 @@ client does between two consensus rounds -- as pure functions reused by:
   * ``dcf_pca`` sharded engine (shard_map over the device mesh),
   * ``distributed.grad_compress`` (robust gradient aggregation).
 
+It also holds the one consensus dispatch those DCF rounds call over a
+named client axis (``aggregate_sharded``: weighted mean, screens, robust
+combine and the top-k wire).
+
 Two inner solvers for Eq. (7) are provided:
 
 ``altmin``   Exact block-coordinate descent alternating the closed forms
@@ -398,101 +402,120 @@ def robust_lam(m_obs: Array, mult: float = 2.0,
     return mult * 1.4826 * _select_median(dev, k_lo, k_hi)
 
 
-def consensus_weights(n_cols: Array | None, part: Array | None,
-                      num_clients: int) -> tuple[Array, Array]:
-    """Normalized consensus weights ``w_i = p_i n_i / sum_j p_j n_j``.
-
-    ``n_cols`` is the (E,) vector of true per-client column counts (``None``
-    => equal blocks), ``part`` the round's 0/1 participation mask (``None``
-    => everyone).  Returns ``(w, wsum)`` where ``wsum = sum_j p_j n_j`` --
-    callers gate the consensus on ``wsum > 0`` (an all-dropout round keeps
-    the previous U).  Normalizing *before* the weighted sum keeps the
-    equal-blocks full-participation case bit-exact with ``mean`` whenever E
-    is a power of two: ``w_i == fl(1/E)`` exactly and scaling by a power of
-    two commutes with every rounding step of the reduction.
-    """
-    raw = jnp.ones((num_clients,), jnp.float32)
-    if n_cols is not None:
-        raw = raw * n_cols
-    if part is not None:
-        raw = raw * part
-    wsum = jnp.sum(raw)
-    return raw / jnp.maximum(wsum, 1e-30), wsum
-
-
 # ---------------------------------------------------------------------------
-# Consensus aggregator dispatch (DESIGN.md Sec. 17)
+# Consensus over a named client axis (DESIGN.md Secs. 14 and 17)
 # ---------------------------------------------------------------------------
-# Every consensus-boundary code path in the DCF engines routes through one
-# of the two functions below (machine-enforced by RPCA-R006): they own the
-# weighted-mean / trimmed-mean / coordinate-median dispatch plus the
-# contribution-divergence screen, so a raw ``jnp.mean`` / ``lax.pmean``
-# reintroduced in an engine step would silently bypass Byzantine
-# robustness.  The ``weighted_mean``-no-screen fast paths reproduce the
-# PR-3 consensus op-for-op (bit-exactness is test-pinned).
+# Every DCF consensus boundary routes through ``aggregate_sharded``
+# (machine-enforced by RPCA-R006), called once per client with the client
+# axis named: under ``jax.vmap(axis_name=...)`` in the simulated engine,
+# ``jax.shard_map`` over the data axes in the sharded one.  It owns the
+# weighted-mean / trimmed-mean / coordinate-median dispatch, the
+# contribution-divergence screen and the top-k wire, so a raw ``mean`` /
+# ``pmean`` reintroduced in a round step would silently bypass Byzantine
+# robustness.  Its ``weighted_mean``-no-screen fast path is the plain
+# Eq. (9) ``pmean``, which under ``vmap`` is the stacked ``mean`` bit for
+# bit.
 
 
-@jax.named_scope(CONSENSUS_SCOPE)
-def aggregate_stacked(
-    cfg: DCFConfig,
-    u_i: Array,
-    u_prev: Array,
-    *,
-    n_cols: Array | None = None,
-    part: Array | None = None,
-    num_clients: int,
-) -> tuple[Array, Array | None]:
-    """Consensus over a stacked ``(E, m, r)`` client axis (simulated engine).
+def topk_k(d: int, frac: float) -> int:
+    """Static kept-entry count for a ``d``-entry factor at ``frac``."""
+    return max(1, min(d, int(round(frac * d))))
 
-    Returns ``(u_new, wsum)``.  ``wsum`` is ``None`` on the unconditional
-    fast path (full participation, no screen, weighted mean) -- callers
-    gate no-op-round handling on ``wsum is not None`` exactly as before;
-    otherwise it is the round's total consensus weight (weighted mean) or
-    the number of surviving one-vote clients (robust aggregators), with
-    ``wsum > 0`` meaning a consensus step actually happened.
+
+def topk_sparsify(g: Array, k: int) -> tuple[Array, Array]:
+    """Top-``k``-by-magnitude entries of ``g`` as (values f32, flat int32
+    indices) -- the wire payload of one compressed consensus message."""
+    flat = g.astype(jnp.float32).ravel()
+    _, idx = jax.lax.top_k(jnp.abs(flat), k)
+    idx = idx.astype(jnp.int32)
+    return flat[idx], idx
+
+
+def topk_reconstruct(vals: Array, idx: Array, size: int) -> Array:
+    """Scatter-add a (values, indices) payload back to a dense flat
+    vector.  Duplicate indices accumulate, so concatenated payloads from
+    E clients reconstruct the *sum* of their sparse messages."""
+    return jnp.zeros((size,), jnp.float32).at[idx.ravel()].add(vals.ravel())
+
+
+def gather_clients(x: Array, axes) -> Array:
+    """All-gather ``x`` over the (possibly tuple) client axes into one
+    stacked ``(E, ...)`` axis -- identical on every client, so stacked
+    post-processing (median, trim, screens) stays lock-step.  Under
+    ``vmap`` the gather is the batched array itself (no copy)."""
+    gathered = jax.lax.all_gather(x, axes)  # (E, ...) -- or nested per axis
+    while gathered.ndim > x.ndim + 1:
+        gathered = gathered.reshape(-1, *x.shape)
+    return gathered
+
+
+def robust_combine_stacked(
+    x: Array,  # (E, ...) stacked per-client payloads
+    active: Array | None,  # (E,) 0/1 participation (None = everyone)
+    aggregator: str,
+    trim_frac: float = 0.25,
+) -> tuple[Array, Array]:
+    """Byzantine-robust one-vote combination over a stacked client axis.
+
+    The robust core behind ``DCFConfig.aggregator`` (DESIGN.md Sec. 17).
+    A client with *any* non-finite entry is dropped entirely (one-vote
+    semantics: a poisoned payload must not vote anywhere), inactive
+    clients are masked to ``+inf`` so they sort past every live value,
+    and the order statistics index a traced live count:
+
+    ``coordinate_median``  ``0.5 * (xs[(c-1)//2] + xs[c//2])`` per
+                           coordinate -- bit-exact with ``jnp.median``
+                           when every client is live; tolerant to any
+                           corruption magnitude while honest clients hold
+                           a strict majority.
+    ``trimmed_mean``       drops ``floor(trim_frac * E)`` extremes per
+                           side (a static count) and averages the middle;
+                           falls back to the median when fewer than one
+                           live value would remain.
+
+    Returns ``(agg, count)`` where ``count`` is the number of surviving
+    clients; ``agg`` is zeros when no client survives (callers gate on
+    ``count > 0`` and keep the previous consensus state).
     """
-    e = num_clients
-    robust = cfg.aggregator != "weighted_mean"
-    if not robust and cfg.divergence_screen is None:
-        if part is None:
-            if n_cols is None:
-                # Eq. (9): FedAvg consensus (bit-exact legacy path).
-                return jnp.mean(u_i, axis=0), None
-            w, _ = consensus_weights(n_cols, None, e)
-            return jnp.sum(w[:, None, None] * u_i, axis=0), None
-        # Dropped-out clients are excluded from the round's consensus;
-        # their weight in later rounds is still the full p_i n_i.
-        w, wsum = consensus_weights(n_cols, part, e)
-        u_g = jnp.where(part[:, None, None] > 0, u_i, u_prev)
-        u = jnp.where(
-            wsum > 0, jnp.sum(w[:, None, None] * u_g, axis=0), u_prev
-        )
-        return u, wsum
-    from repro.distributed import grad_compress as gcomp
+    e = x.shape[0]
+    flat = x.reshape(e, -1).astype(jnp.float32)
+    finite = jnp.all(jnp.isfinite(flat), axis=1)
+    keep = finite if active is None else finite & (active > 0)
+    cnt = jnp.sum(keep.astype(jnp.int32))
+    xs = jnp.sort(jnp.where(keep[:, None], flat, jnp.inf), axis=0)
+    c = jnp.maximum(cnt, 1)
+    med = 0.5 * (xs[(c - 1) // 2] + xs[c // 2])
+    if aggregator == "coordinate_median":
+        agg = med
+    elif aggregator == "trimmed_mean":
+        k = int(trim_frac * e)
+        pos = jnp.arange(e)[:, None]
+        take = (pos >= k) & (pos < c - k)
+        tsum = jnp.sum(jnp.where(take, xs, 0.0), axis=0)
+        denom = c - 2 * k
+        agg = jnp.where(denom >= 1, tsum / jnp.maximum(denom, 1), med)
+    else:
+        raise ValueError(f"unknown robust aggregator {aggregator!r}")
+    agg = jnp.where(cnt > 0, agg, 0.0)
+    return agg.reshape(x.shape[1:]), cnt
 
-    active = jnp.ones((e,), jnp.float32) if part is None else part
-    delta = (u_i - u_prev).astype(jnp.float32)
-    if cfg.divergence_screen is not None:
-        active = active * gcomp.divergence_screen_mask(
-            delta, active, cfg.divergence_screen
-        )
-    if robust:
-        # One vote per client: a median/trim of column-count-weighted
-        # factors has no consistent meaning, so ragged ``n_cols`` weights
-        # are deliberately ignored here.
-        agg, cnt = gcomp.robust_combine_stacked(
-            delta, active, cfg.aggregator, cfg.trim_frac
-        )
-        u = jnp.where(cnt > 0, u_prev + agg.astype(u_prev.dtype), u_prev)
-        return u, cnt.astype(jnp.float32)
-    # Screened weighted mean: recompute the PR-3 weights over the clients
-    # that survived the screen.
-    w, wsum = consensus_weights(n_cols, active, e)
-    u_g = jnp.where(active[:, None, None] > 0, u_i, u_prev)
-    u = jnp.where(
-        wsum > 0, jnp.sum(w[:, None, None] * u_g, axis=0), u_prev
-    )
-    return u, wsum
+
+def screen_from_norms(nrm: Array, active: Array,
+                      threshold: float) -> Array:
+    """Contribution-divergence screen from per-client payload norms:
+    quarantine (return 0) any client whose norm is non-finite or exceeds
+    ``threshold`` times the median norm of the live cohort.
+
+    The median baseline is computed over *active, finite* clients only --
+    a quarantined client must not drag the baseline it is judged against.
+    With every live norm at zero (a converged solve) nothing trips: the
+    comparison floor keeps ``0 <= threshold * eps`` true.
+    """
+    ok = jnp.isfinite(nrm) & (active > 0)
+    cnt = jnp.maximum(jnp.sum(ok.astype(jnp.int32)), 1)
+    med = _masked_median(nrm, ok, cnt)
+    keep = jnp.isfinite(nrm) & (nrm <= threshold * jnp.maximum(med, 1e-30))
+    return keep.astype(jnp.float32)
 
 
 @jax.named_scope(CONSENSUS_SCOPE)
@@ -501,45 +524,51 @@ def aggregate_sharded(
     u_i: Array,
     u_prev: Array,
     *,
-    axes: tuple[str, ...],
+    axes,
     pt: Array,
     n_i: Array,
     uniform: bool,
     reduce_m=None,
-) -> tuple[Array, Array | None]:
-    """Consensus across mesh shards (SPMD engine); called per shard.
+    err: Array | None = None,
+):
+    """The consensus of one round, called once per client over the named
+    client ``axes``; every client gets the same result.
 
-    ``pt`` is this shard's participation weight for the round (1.0 when no
-    schedule), ``n_i`` its true column count (1.0 uniform base when not
-    ragged), ``uniform`` selects the bit-exact ``pmean`` fast path (no
-    schedule, no ragged tail).  ``reduce_m`` psums row-partial scalars over
-    the model axis so screen norms see full rows.  All collectives run
-    unconditionally on every shard (lock-step invariant); the robust paths
-    all-gather the stacked client payloads so every shard computes the
-    identical aggregate.  Returns ``(u_new, wsum)`` with the same ``wsum``
-    contract as :func:`aggregate_stacked`.
+    ``pt`` is this client's participation weight for the round (1.0 when
+    no schedule), ``n_i`` its true column count (1.0 uniform base on equal
+    blocks), ``uniform`` selects the bit-exact ``pmean`` fast path (no
+    schedule, no ragged tail).  ``reduce_m`` psums row-partial scalars
+    over the sharded engine's model axis so screen norms see full rows.
+    All collectives run unconditionally on every client (lock-step
+    invariant); the robust paths all-gather the stacked client payloads
+    so every client computes the identical aggregate.
+
+    Returns ``(u_new, wsum)``.  ``wsum`` is ``None`` on the unconditional
+    fast path; otherwise it is the round's total consensus weight
+    (weighted mean) or the number of surviving one-vote clients (robust
+    aggregators), with ``wsum > 0`` meaning a consensus step happened.
+    With ``err`` (the client's error-feedback residual) the round runs
+    the top-k wire of ``cfg.consensus_compress`` and returns ``(u_new,
+    wsum, err_new)``.
     """
     robust = cfg.aggregator != "weighted_mean"
     if reduce_m is None:
         reduce_m = _identity
+    if err is not None:
+        return _topk_wire(cfg, u_i, u_prev, axes, pt, n_i, uniform,
+                          reduce_m, err)
     if not robust and cfg.divergence_screen is None:
         if uniform:
             return jax.lax.pmean(u_i, axes), None  # Eq. (9) consensus
-        # Participation-weighted consensus (Eq. 9 generalized):
-        # U = sum_i p_i n_i U_i / sum_i p_i n_i, one psum of the
-        # pre-scaled factor -- same 2 E m r communication bound.
-        u_g = jnp.where(pt > 0, u_i, u_prev)
-        raw_w = pt * n_i
-        wsum = jax.lax.psum(raw_w, axes)
-        wgt = raw_w / jnp.maximum(wsum, 1e-30)
+        # Participation-weighted consensus (Eq. 9 generalized): one psum
+        # of the pre-scaled factor -- same 2 E m r communication bound.
+        u_g, wgt, wsum = _weighted(u_i, u_prev, pt, n_i, axes)
         u_cand = jax.lax.psum(wgt * u_g, axes)
         return jnp.where(wsum > 0, u_cand, u_prev), wsum
-    from repro.distributed import grad_compress as gcomp
-
     one = jnp.ones((), jnp.float32)
     delta = (u_i - u_prev).astype(jnp.float32)
-    stacked = gcomp.gather_clients(delta, axes)  # (E, m_loc, r)
-    active = gcomp.gather_clients(pt * one, axes)  # (E,)
+    stacked = gather_clients(delta, axes)  # (E, m_loc, r)
+    active = gather_clients(pt * one, axes)  # (E,)
     e = stacked.shape[0]
     # One-vote finiteness is a *global* per-client property: psum the
     # non-finite counts over the model axis so every row shard agrees on
@@ -552,20 +581,23 @@ def aggregate_sharded(
     if cfg.divergence_screen is not None:
         sq = jnp.sum(stacked.reshape(e, -1) ** 2, axis=1)
         nrm = jnp.sqrt(reduce_m(sq))
-        active = active * gcomp.screen_from_norms(
+        active = active * screen_from_norms(
             nrm, active, cfg.divergence_screen
         )
     if robust:
-        agg, cnt = gcomp.robust_combine_stacked(
+        # One vote per client: a median/trim of column-count-weighted
+        # factors has no consistent meaning, so ragged weights are
+        # deliberately ignored here.
+        agg, cnt = robust_combine_stacked(
             stacked, active, cfg.aggregator, cfg.trim_frac
         )
         u_new = jnp.where(
             cnt > 0, u_prev + agg.astype(u_prev.dtype), u_prev
         )
         return u_new, cnt.astype(jnp.float32)
-    # Screened weighted mean over the gathered stack (every shard holds
+    # Screened weighted mean over the gathered stack (every client holds
     # the same stack, so no further collective is needed).
-    n_all = gcomp.gather_clients(n_i * one, axes)
+    n_all = gather_clients(n_i * one, axes)
     raw = active * n_all
     wsum = jnp.sum(raw)
     w = raw / jnp.maximum(wsum, 1e-30)
@@ -576,6 +608,72 @@ def aggregate_sharded(
     )
     u_new = jnp.where(wsum > 0, u_prev + step.astype(u_prev.dtype), u_prev)
     return u_new, wsum
+
+
+def _weighted(u_i, u_prev, pt, n_i, axes):
+    """This client's payload and weight in the round's weighted consensus
+    ``U = sum_i p_i n_i U_i / sum_i p_i n_i``, and the round's total
+    weight.  A dropped-out client is excluded (its payload is the previous
+    U, its weight 0); its weight in later rounds is still the full
+    ``p_i n_i``."""
+    raw_w = pt * n_i
+    wsum = jax.lax.psum(raw_w, axes)
+    return (jnp.where(pt > 0, u_i, u_prev),
+            raw_w / jnp.maximum(wsum, 1e-30), wsum)
+
+
+def _topk_wire(cfg: DCFConfig, u_i, u_prev, axes, pt, n_i, uniform,
+               reduce_m, err):
+    """Top-k consensus wire with error feedback (DESIGN.md Sec. 14).
+
+    The consensus runs in delta form -- the weights sum to 1, so
+    ``sum_i w_i U_i == U + sum_i w_i (U_i - U)`` -- and each client ships
+    only the top-k of its weighted delta plus its residual ``err`` as a
+    compact (k f32 values, k int32 indices) payload.  One all-gather
+    moves the E payloads and every client scatter-adds the same
+    concatenated sequence (lock-step safe, like a real psum).  What the
+    top-k dropped stays in the returned residual and rides the next
+    round's message, so compression error never accumulates:
+
+        shipped_t + err_t = contrib_t + err_{t-1}
+
+    (exact when k == m r).  A dropped client ships zeros and keeps its
+    residual.  Robust aggregators ship the *unweighted* delta and, on
+    receive, combine the E reconstructed payloads one vote each (after
+    the divergence screen on the shipped norms); a poisoned payload must
+    not poison its own residual forever, so non-finite residuals reset.
+    """
+    robust = cfg.aggregator != "weighted_mean"
+    wsum = None
+    if robust:
+        wgt = jnp.float32(1.0)
+    elif uniform:
+        wgt = jnp.float32(1.0 / jax.lax.axis_size(axes))
+    else:
+        u_i, wgt, wsum = _weighted(u_i, u_prev, pt, n_i, axes)
+    g = (wgt * (u_i - u_prev)).astype(jnp.float32) + err
+    k = topk_k(g.size, cfg.consensus_compress.topk_frac)
+    vals, idx = topk_sparsify(g, k)
+    err_new = g - topk_reconstruct(vals, idx, g.size).reshape(g.shape)
+    vals = jnp.where(pt > 0, vals, jnp.zeros_like(vals))
+    err_new = jnp.where(pt > 0, err_new, err)
+    vals_g = gather_clients(vals, axes)  # (E, k)
+    idx_g = gather_clients(idx, axes)
+    if not robust:
+        delta = topk_reconstruct(vals_g, idx_g, g.size).reshape(g.shape)
+        return u_prev + delta.astype(u_prev.dtype), wsum, err_new
+    err_new = jnp.where(jnp.isfinite(err_new), err_new, 0.0)
+    act = gather_clients(pt, axes)
+    if cfg.divergence_screen is not None:
+        nrm = jnp.sqrt(reduce_m(jnp.sum(vals_g * vals_g, axis=1)))
+        act = act * screen_from_norms(nrm, act, cfg.divergence_screen)
+    recon = jax.vmap(lambda vv, ii: topk_reconstruct(vv, ii, g.size))(
+        vals_g, idx_g)
+    delta, cnt = robust_combine_stacked(
+        recon.reshape((-1,) + g.shape), act, cfg.aggregator, cfg.trim_frac
+    )
+    return (u_prev + delta.astype(u_prev.dtype), cnt.astype(jnp.float32),
+            err_new)
 
 
 @dataclass(frozen=True)

@@ -193,27 +193,14 @@ def resolve_faults(faults) -> Array | None:
 # ---------------------------------------------------------------------------
 # Traced injection at the consensus boundary
 # ---------------------------------------------------------------------------
-def round_codes(table: Array, t: Array) -> Array:
-    """The (E,) code row for round ``t`` (the schedule wraps)."""
-    return table[jnp.mod(t, table.shape[0])]
-
-
 def corrupt_payload(code: Array, u_i: Array, u_prev: Array) -> Array:
-    """Apply the payload faults to what each client ships.
-
-    ``code`` broadcasts against ``u_i``'s leading layout: pass the (E,)
-    row with a stacked ``(E, m, r)`` factor (simulated engine) or this
-    shard's scalar code with its local ``(m, r)`` factor (SPMD engine).
-    ``CRASH``/``FLAKY`` leave the payload untouched -- their effect is a
-    dropped *vote*, applied through :func:`live_mask`.
+    """Apply this client's payload fault (its scalar ``code``) to what it
+    ships.  ``CRASH``/``FLAKY`` leave the payload untouched -- their
+    effect is a dropped *vote*, applied through :func:`live_mask`.
     """
-    c = code
-    while c.ndim < u_i.ndim:
-        c = c[..., None]
-    u = jnp.where(c == NAN, jnp.float32(jnp.nan), u_i)
-    u = jnp.where(c == CORRUPT, CORRUPT_SCALE * u_i, u)
-    u = jnp.where(c == STALE, jnp.broadcast_to(u_prev, u_i.shape), u)
-    return u
+    u = jnp.where(code == NAN, jnp.float32(jnp.nan), u_i)
+    u = jnp.where(code == CORRUPT, CORRUPT_SCALE * u_i, u)
+    return jnp.where(code == STALE, u_prev, u)
 
 
 def live_mask(code: Array) -> Array:

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import factorized as fz
-from repro.distributed.multihost import topk_k
+from repro.core.dcf_pca import client_init, client_step
 
 Array = jax.Array
 
@@ -50,11 +50,13 @@ class CompressConfig:
     topk_frac: float | None = None
 
     def dcf(self) -> fz.DCFConfig:
+        """The DCF round of one aggregation; ``topk_frac`` sets its wire."""
         return fz.DCFConfig(
             rank=self.rank, outer_iters=self.rounds,
             local_iters=self.local_iters, inner_sweeps=self.inner_sweeps,
             rho=self.rho, eta0=self.eta, lr_schedule="fixed",
             precondition="lipschitz", impl="ref",
+            consensus_compress=None if self.topk_frac is None else self,
         )
 
 
@@ -88,251 +90,41 @@ def _robust_sigma(g: Array, axes, eps: float = 1e-6) -> Array:
     return jax.lax.pmean(jnp.maximum(1.4826 * sigma, eps * rms), axes)
 
 
-def topk_sparsify(g: Array, k: int) -> tuple[Array, Array]:
-    """Top-``k``-by-magnitude entries of ``g`` as (values f32, flat int32
-    indices) -- the wire payload of one compressed consensus message."""
-    flat = g.astype(jnp.float32).ravel()
-    _, idx = jax.lax.top_k(jnp.abs(flat), k)
-    idx = idx.astype(jnp.int32)
-    return flat[idx], idx
-
-
-def topk_reconstruct(vals: Array, idx: Array, size: int) -> Array:
-    """Scatter-add a (values, indices) payload back to a dense flat
-    vector.  Duplicate indices accumulate, so concatenated payloads from
-    E clients reconstruct the *sum* of their sparse messages."""
-    return jnp.zeros((size,), jnp.float32).at[idx.ravel()].add(vals.ravel())
-
-
-def compressed_consensus_sum(
-    contrib: Array,  # this shard's dense (already weighted) contribution
-    axes,  # mesh axis name(s) to sum over
-    k: int,
-    err: Array,  # error-feedback residual, same shape as contrib
-    active: Array | None = None,  # scalar >0 when this shard participates
-) -> tuple[Array, Array]:
-    """Error-feedback top-k replacement for ``psum(contrib, axes)``.
-
-    Each shard ships the top-k of ``contrib + err`` as a compact
-    (k f32 values, k int32 indices) payload; one all-gather moves the
-    E payloads and every shard scatter-adds the *same* concatenated
-    sequence, so the reconstructed sum is bit-identical across shards
-    (lock-step safe, like a real psum).  What the top-k dropped stays in
-    the returned residual and rides the next round's message -- the
-    error-feedback invariant (DESIGN.md Sec. 14):
-
-        shipped_t + err_t = contrib_t + err_{t-1}
-
-    An inactive shard (``active == 0``) ships zero values (the collective
-    still runs -- SPMD -- but contributes nothing) and keeps its residual
-    untouched.  Returns ``(sum, err_new)``; exact when ``k == size``.
-    """
-    g = contrib.astype(jnp.float32) + err
-    vals, idx = topk_sparsify(g, k)
-    err_new = g - topk_reconstruct(vals, idx, g.size).reshape(g.shape)
-    if active is not None:
-        vals = jnp.where(active > 0, vals, jnp.zeros_like(vals))
-        err_new = jnp.where(active > 0, err_new, err)
-    vals_g = jax.lax.all_gather(vals, axes)  # (E, k)
-    idx_g = jax.lax.all_gather(idx, axes)
-    while vals_g.ndim > 2:  # tuple axes gather one leading dim per axis
-        vals_g = vals_g.reshape(-1, vals.shape[0])
-        idx_g = idx_g.reshape(-1, idx.shape[0])
-    total = topk_reconstruct(vals_g, idx_g, g.size).reshape(g.shape)
-    return total.astype(contrib.dtype), err_new
-
-
 def consensus_compress(
     g_local: Array,  # (m, k) this worker's gradient
     axes,  # mesh axis name(s) of the DP dimension
     ccfg: CompressConfig,
     key: Array,
 ) -> Array:
-    """Robust aggregate of a 2-D gradient leaf across the DP axes."""
-    m, k = g_local.shape
+    """Robust aggregate of a 2-D gradient leaf across the DP axes: the DCF
+    round (:func:`client_step`) scanned ``ccfg.rounds`` times, each
+    worker one client."""
     cfg = ccfg.dcf()
     lam = ccfg.lam_mult * _robust_sigma(g_local, axes) + 1e-12
-    n_workers = jax.lax.psum(1, axes)
 
     # Sketch init: U0 = pmean(G_i Omega) -- one power-iteration step toward
     # the dominant shared column space (Omega shared via the common key).
-    omega = jax.random.normal(key, (k, ccfg.rank), jnp.float32)
+    omega = jax.random.normal(key, (g_local.shape[1], ccfg.rank),
+                              jnp.float32)
     u = jax.lax.pmean(g_local.astype(jnp.float32) @ omega, axes)
     u = u / (jnp.linalg.norm(u, axis=0, keepdims=True) + 1e-12)
-    v = jnp.zeros((k, ccfg.rank), jnp.float32)
+    v = jnp.zeros((g_local.shape[1], ccfg.rank), jnp.float32)
 
-    k_keep = (None if ccfg.topk_frac is None
-              else topk_k(m * ccfg.rank, ccfg.topk_frac))
-
-    def round_(carry, t):
-        u, v, err = carry
-        u_i, v, _ = fz.local_round(
-            u, v, g_local.astype(jnp.float32), cfg=cfg, lam=lam,
-            n_frac=1.0 / n_workers, eta=cfg.lr(t),
-        )
-        if k_keep is None:
-            return (jax.lax.pmean(u_i, axes), v, err), None
-        # pmean(u_i) == u + sum_i (u_i - u)/E, shipped top-k compressed.
-        delta, err = compressed_consensus_sum(
-            (u_i - u) / n_workers, axes, k_keep, err)
-        return (u + delta, v, err), None
-
-    err0 = jnp.zeros_like(u)
-    (u, v, _), _ = jax.lax.scan(round_, (u, v, err0),
-                                jnp.arange(ccfg.rounds))
-    v_mean = jax.lax.pmean(v, axes)  # (k, r)
-    return (u @ v_mean.T).astype(g_local.dtype)
-
-
-def gather_clients(x: Array, axes) -> Array:
-    """All-gather ``x`` over the (possibly tuple) mesh axes into one
-    stacked ``(E, ...)`` client axis -- identical on every shard, so
-    stacked post-processing (median, trim, screens) stays lock-step."""
-    gathered = jax.lax.all_gather(x, axes)  # (E, ...) -- or nested per axis
-    while gathered.ndim > x.ndim + 1:
-        gathered = gathered.reshape(-1, *x.shape)
-    return gathered
+    step = partial(client_step, cfg, block=g_local.astype(jnp.float32),
+                   w=None, n_i=None, lam0=lam, t0=0, sched=None,
+                   faults=None, axes=axes, track=False)
+    c, _ = jax.lax.scan(lambda c, t: (step(c, t), None),
+                        client_init(cfg, u, v), jnp.arange(ccfg.rounds))
+    v_mean = jax.lax.pmean(c["v"], axes)  # (k, r)
+    return (c["u"] @ v_mean.T).astype(g_local.dtype)
 
 
 def median_aggregate(g: Array, axes) -> Array:
     """Coordinate-wise median over the DP workers: the Byzantine-robust
     fallback for leaves too small to factorize (norm scales, biases).
     Costs one all-gather of a small tensor."""
-    gathered = gather_clients(g, axes)
+    gathered = fz.gather_clients(g, axes)
     return jnp.median(gathered.astype(jnp.float32), axis=0).astype(g.dtype)
-
-
-def robust_combine_stacked(
-    x: Array,  # (E, ...) stacked per-client payloads
-    active: Array | None,  # (E,) 0/1 participation (None = everyone)
-    aggregator: str,
-    trim_frac: float = 0.25,
-) -> tuple[Array, Array]:
-    """Byzantine-robust one-vote combination over a stacked client axis.
-
-    The robust core behind ``DCFConfig.aggregator`` (DESIGN.md Sec. 17),
-    extending :func:`median_aggregate` with participation masking,
-    NaN/inf quarantine and a trimmed-mean variant.  A client with *any*
-    non-finite entry is dropped entirely (one-vote semantics: a poisoned
-    payload must not vote anywhere), inactive clients are masked to
-    ``+inf`` so they sort past every live value, and the order statistics
-    index a traced live count:
-
-    ``coordinate_median``  ``0.5 * (xs[(c-1)//2] + xs[c//2])`` per
-                           coordinate -- bit-exact with ``jnp.median``
-                           when every client is live; tolerant to any
-                           corruption magnitude while honest clients hold
-                           a strict majority.
-    ``trimmed_mean``       drops ``floor(trim_frac * E)`` extremes per
-                           side (a static count) and averages the middle;
-                           falls back to the median when fewer than one
-                           live value would remain.
-
-    Returns ``(agg, count)`` where ``count`` is the number of surviving
-    clients; ``agg`` is zeros when no client survives (callers gate on
-    ``count > 0`` and keep the previous consensus state).
-    """
-    e = x.shape[0]
-    flat = x.reshape(e, -1).astype(jnp.float32)
-    finite = jnp.all(jnp.isfinite(flat), axis=1)
-    keep = finite if active is None else finite & (active > 0)
-    cnt = jnp.sum(keep.astype(jnp.int32))
-    xs = jnp.sort(jnp.where(keep[:, None], flat, jnp.inf), axis=0)
-    c = jnp.maximum(cnt, 1)
-    med = 0.5 * (xs[(c - 1) // 2] + xs[c // 2])
-    if aggregator == "coordinate_median":
-        agg = med
-    elif aggregator == "trimmed_mean":
-        k = int(trim_frac * e)
-        pos = jnp.arange(e)[:, None]
-        take = (pos >= k) & (pos < c - k)
-        tsum = jnp.sum(jnp.where(take, xs, 0.0), axis=0)
-        denom = c - 2 * k
-        agg = jnp.where(denom >= 1, tsum / jnp.maximum(denom, 1), med)
-    else:
-        raise ValueError(f"unknown robust aggregator {aggregator!r}")
-    agg = jnp.where(cnt > 0, agg, 0.0)
-    return agg.reshape(x.shape[1:]), cnt
-
-
-def screen_from_norms(nrm: Array, active: Array,
-                      threshold: float) -> Array:
-    """Contribution-divergence screen from precomputed per-client payload
-    norms: quarantine (return 0) any client whose norm is non-finite or
-    exceeds ``threshold`` times the median norm of the live cohort.
-
-    The median baseline is computed over *active, finite* clients only --
-    a quarantined client must not drag the baseline it is judged against.
-    With every live norm at zero (a converged solve) nothing trips: the
-    comparison floor keeps ``0 <= threshold * eps`` true.
-    """
-    ok = jnp.isfinite(nrm) & (active > 0)
-    cnt = jnp.maximum(jnp.sum(ok.astype(jnp.int32)), 1)
-    med = fz._masked_median(nrm, ok, cnt)
-    keep = jnp.isfinite(nrm) & (nrm <= threshold * jnp.maximum(med, 1e-30))
-    return keep.astype(jnp.float32)
-
-
-def divergence_screen_mask(delta: Array, active: Array,
-                           threshold: float) -> Array:
-    """Screen mask for a stacked ``(E, ...)`` delta payload (the simulated
-    engine's consensus boundary): per-client Frobenius norms fed to
-    :func:`screen_from_norms`."""
-    e = delta.shape[0]
-    nrm = jnp.sqrt(
-        jnp.sum(delta.reshape(e, -1).astype(jnp.float32) ** 2, axis=1)
-    )
-    return screen_from_norms(nrm, active, threshold)
-
-
-def compressed_consensus_robust(
-    contrib: Array,  # this shard's dense (unweighted) delta
-    axes,
-    k: int,
-    err: Array,
-    active: Array | None,
-    aggregator: str,
-    trim_frac: float = 0.25,
-    screen: float | None = None,
-    reduce_m=None,
-) -> tuple[Array, Array, Array]:
-    """Robust-aggregating sibling of :func:`compressed_consensus_sum`.
-
-    Same wire format and error-feedback invariant -- each shard ships the
-    top-k of ``contrib + err`` and one all-gather moves the E payloads --
-    but instead of scatter-adding the concatenated payloads, every shard
-    reconstructs the E *per-client* dense deltas and combines them with
-    :func:`robust_combine_stacked` (optionally after the divergence
-    screen on the shipped norms).  Deterministic and identical across
-    shards, so lock-step is preserved.  Returns
-    ``(delta, err_new, count)``.
-    """
-    g = contrib.astype(jnp.float32) + err
-    vals, idx = topk_sparsify(g, k)
-    err_new = g - topk_reconstruct(vals, idx, g.size).reshape(g.shape)
-    if active is not None:
-        vals = jnp.where(active > 0, vals, jnp.zeros_like(vals))
-        err_new = jnp.where(active > 0, err_new, err)
-    vals_g = gather_clients(vals, axes)  # (E, k)
-    idx_g = gather_clients(idx, axes)
-    e = vals_g.shape[0]
-    recon = jax.vmap(
-        lambda vv, ii: topk_reconstruct(vv, ii, g.size)
-    )(vals_g, idx_g)  # (E, size)
-    act = (gather_clients(jnp.asarray(1.0 if active is None else active,
-                                      jnp.float32) * jnp.ones((),
-                                                              jnp.float32),
-                          axes))
-    if screen is not None:
-        # "Shipped delta norm": judged on what actually crossed the wire.
-        sq = jnp.sum(vals_g * vals_g, axis=1)
-        if reduce_m is not None:
-            sq = reduce_m(sq)
-        act = act * screen_from_norms(jnp.sqrt(sq), act, screen)
-    delta, cnt = robust_combine_stacked(
-        recon.reshape((e,) + g.shape), act, aggregator, trim_frac
-    )
-    return delta.astype(contrib.dtype), err_new, cnt
 
 
 def aggregate_leaf(g: Array, axes, ccfg: CompressConfig, key: Array) -> Array:
@@ -378,6 +170,6 @@ def compression_ratio(shape: tuple[int, ...], ccfg: CompressConfig) -> float:
     if ccfg.topk_frac is None:
         round_bytes = m * ccfg.rank * 4
     else:
-        round_bytes = topk_k(m * ccfg.rank, ccfg.topk_frac) * (4 + 4)
+        round_bytes = fz.topk_k(m * ccfg.rank, ccfg.topk_frac) * (4 + 4)
     compressed = ccfg.rounds * round_bytes + k * ccfg.rank * 4
     return compressed / (m * k * 4)

@@ -35,6 +35,8 @@ import sys
 import threading
 import time
 
+from repro.core import factorized as fz
+
 ENV_COORDINATOR = "RPCA_COORDINATOR"
 ENV_NUM_PROCESSES = "RPCA_NUM_PROCESSES"
 ENV_PROCESS_ID = "RPCA_PROCESS_ID"
@@ -290,11 +292,6 @@ def launch_workers(code: str, num_processes: int = 2,
 # consensus wire accounting
 
 
-def topk_k(d: int, frac: float) -> int:
-    """Static kept-entry count for a ``d``-entry factor at ``frac``."""
-    return max(1, min(d, int(round(frac * d))))
-
-
 def consensus_wire_model(m: int, rank: int, num_clients: int,
                          compress=None) -> dict[str, float]:
     """Modelled consensus bytes one client moves per round.
@@ -315,7 +312,7 @@ def consensus_wire_model(m: int, rank: int, num_clients: int,
         shipped = dense
         k = d
     else:
-        k = topk_k(d, float(frac))
+        k = fz.topk_k(d, float(frac))
         shipped = 8 * k * num_clients
     return {
         "dense_bytes": float(dense),

@@ -18,13 +18,9 @@ from repro import rpca
 from repro.core import factorized as fz
 from repro.core import problems as prob
 from repro.core import validate
+from repro.core.factorized import topk_reconstruct, topk_sparsify
 from repro.distributed import multihost as mh
-from repro.distributed.grad_compress import (
-    CompressConfig,
-    compression_ratio,
-    topk_reconstruct,
-    topk_sparsify,
-)
+from repro.distributed.grad_compress import CompressConfig, compression_ratio
 
 dcf = importlib.import_module("repro.core.dcf_pca")
 
@@ -72,7 +68,7 @@ def test_compression_ratio_counts_index_bytes():
         m * k * 4)
     assert compression_ratio(shape, dense) == pytest.approx(expect)
     topk = CompressConfig(rank=8, rounds=4, topk_frac=0.05)
-    kk = mh.topk_k(m * topk.rank, 0.05)
+    kk = fz.topk_k(m * topk.rank, 0.05)
     expect_topk = (topk.rounds * kk * (4 + 4) + k * topk.rank * 4) / (
         m * k * 4)
     assert compression_ratio(shape, topk) == pytest.approx(expect_topk)
@@ -87,7 +83,7 @@ def test_consensus_wire_model():
     model = mh.consensus_wire_model(256, 8, 4, CompressConfig(
         topk_frac=0.025))
     d = 256 * 8
-    k = mh.topk_k(d, 0.025)
+    k = fz.topk_k(d, 0.025)
     assert model["dense_bytes"] == 2 * d * 4
     assert model["shipped_bytes"] == 8 * k * 4
     assert model["ratio"] == pytest.approx(2 * d * 4 / (8 * k * 4))

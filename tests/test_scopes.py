@@ -143,9 +143,13 @@ CHECKS = {
                                                 "triangular_solve"),
                "rpca.ridge"),
         _under(ops, lambda opc, n: "rpca.ridge" in n, "rpca.round")),
+    # under the client map too, ``rpca.consensus`` is a component of its
+    # own (not ``vmap(rpca.consensus)``), as the trace readers match it
     "client mean": lambda ops: (
         _under(ops, lambda opc, n: opc == "reduce" and "rpca.consensus" in n,
-               "rpca.round")),
+               "rpca.round"),
+        _under(ops, lambda opc, n: opc == "reduce" and "rpca.consensus" in n,
+               "rpca.consensus")),
     "psum": lambda ops: _under(
         ops, lambda opc, n: opc.startswith("all-reduce"), "rpca.consensus"),
 }

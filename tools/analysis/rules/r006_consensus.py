@@ -2,9 +2,10 @@
 
 Invariant (PR 10): every consensus boundary -- the point in a solver
 step where per-client factor payloads (``u_i`` / ``v_i`` stacks or
-shards) combine into the shared iterate -- must route through the
-aggregator dispatch (``factorized.aggregate_stacked`` /
-``aggregate_sharded`` or the ``grad_compress`` robust combiners).  A raw
+shards) combine into the shared iterate -- must route through the one
+consensus dispatch, ``factorized.aggregate_sharded``, which the DCF round
+step (``dcf_pca.client_step``) calls per client over the named client
+axis under ``vmap`` or ``shard_map``.  A raw
 ``jnp.mean(u_i, axis=0)`` / ``lax.pmean(u_i, axes)`` / ``psum(u_i, ...)``
 hand-rolls the weighted mean at one boundary and silently ignores
 ``DCFConfig.aggregator`` / ``divergence_screen`` there: Byzantine
@@ -62,7 +63,7 @@ def check(mod: ModuleInfo) -> list[Finding]:
             "RPCA-R006", mod.display_path, node.lineno, qual,
             f"raw {leaf}({payload}, ...) combines client factor payloads "
             f"inside a solver step: route this consensus boundary through "
-            f"aggregate_stacked / aggregate_sharded so "
+            f"factorized.aggregate_sharded so "
             f"DCFConfig.aggregator and the divergence screen apply here "
             f"too",
         ))
